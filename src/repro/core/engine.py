@@ -29,6 +29,7 @@ traffic is O(k), independent of catalog size.
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from dataclasses import dataclass, field
@@ -69,6 +70,8 @@ from repro.obs import profile as obs_profile
 from repro.obs import trace as obs_trace
 
 MODELS = ("dbranch", "dbens", "dtree", "rforest", "knn")
+
+log = logging.getLogger(__name__)
 
 # sentinel: "no per-call override — use the engine default"
 _UNSET = object()
@@ -156,8 +159,8 @@ class SearchEngine:
     pinned tie-break contract — results are bitwise-identical for every
     shard count, and ranked host traffic stays O(k) regardless of it.
     ``shard_mesh``: None auto-builds a "shards" mesh when the backend
-    has >= n_shards devices (shard_map via the repro.compat shim),
-    False forces the single-device vmap fallback, or pass a Mesh.
+    has >= n_shards devices (jax.shard_map), False forces the
+    single-device vmap fallback, or pass a Mesh.
 
     ``live=True`` (DESIGN.md §12) makes the catalog MUTABLE: ``append``
     seals new rows into delta segments (global ids append-ordered and
@@ -1702,6 +1705,9 @@ class SearchEngine:
         # use_jax_fit=False keeps the per-request numpy oracle
         t0 = time.perf_counter()
         fitted = []   # (slot, model, boxsets, pos, neg, incl, mr, t_fit)
+        # window-wide device-fit failures that fell back to the numpy
+        # trainer: answers stay exact, so only this count shows it
+        fit_fallbacks = 0
         if self.use_jax_fit:
             # slot -> ("device", lo, hi, entries) or List[BoxSet] fallback
             boxsets_by_slot: Dict[int, object] = {}
@@ -1716,6 +1722,10 @@ class SearchEngine:
                         return_device=True, frange=view.frange)
                 except Exception:  # noqa: BLE001 — degrade, don't die
                     entries = None  # batch-wide failure: per-request oracle
+                    fit_fallbacks += 1
+                    log.warning("batched device fit failed for %d "
+                                "requests; refitting each on the numpy "
+                                "trainer", len(items), exc_info=True)
                 for j, it in enumerate(items):
                     if entries is not None and not isinstance(
                             entries[j], Exception):
@@ -1823,15 +1833,18 @@ class SearchEngine:
         base["path"] = "index"
         base["batch_size"] = nq
         base["batch_fit_s"] = fit_wall
-        base["fit_path"] = "jax" if self.use_jax_fit else "numpy"
+        base["batch_fit_fallbacks"] = fit_fallbacks
         for q, (slot, model, boxes, pos, neg, incl, m, t_fit) in enumerate(
                 fitted):
             ids, sc = ranked[q]
-            if isinstance(boxes, tuple) and boxes[0] == "device":
+            # per request: which trainer produced THESE boxes
+            on_device = isinstance(boxes, tuple) and boxes[0] == "device"
+            if on_device:
                 nb = int(sum(cnt for _, _, cnt in boxes[3]))
             else:
                 nb = int(sum(bs.n_boxes for bs in boxes))
-            stats = {**base, "n_boxes": nb}
+            stats = {**base, "n_boxes": nb,
+                     "fit_path": "jax" if on_device else "numpy"}
             results[slot] = QueryResult(model, ids, sc, t_fit, t_query,
                                         stats)
         return results

@@ -114,11 +114,15 @@ class ZoneMapIndex:
         row order without any host de-mux; padded Morton slots are never
         gathered because only the n_rows real rows appear here."""
         if self._dev_inv_perm is None:
-            valid = self.perm >= 0
-            inv = np.empty(self.n_rows, np.int32)
-            inv[self.perm[valid]] = np.nonzero(valid)[0].astype(np.int32)
-            self._dev_inv_perm = jnp.asarray(inv)
+            self._dev_inv_perm = jnp.asarray(self.inv_perm())
         return self._dev_inv_perm
+
+    def inv_perm(self) -> np.ndarray:
+        """Host copy of the inverse permutation device_inv_perm caches."""
+        valid = self.perm >= 0
+        inv = np.empty(self.n_rows, np.int32)
+        inv[self.perm[valid]] = np.nonzero(valid)[0].astype(np.int32)
+        return inv
 
     def device_gids(self) -> jax.Array:
         """[NB, block] int32 GLOBAL row id per (block, slot) — the
@@ -542,8 +546,9 @@ class ShardedZoneMapIndex:
             for i, sh in enumerate(self.shards):
                 if sh.n_rows:
                     base = i * nbm * self.block if mesh is None else 0
-                    inv[i, :sh.n_rows] = \
-                        np.asarray(sh.device_inv_perm()) + base
+                    # host-side: a shard's own device mirror would
+                    # land on the default device, not the shard's
+                    inv[i, :sh.n_rows] = sh.inv_perm() + base
             self.device_arrays(mesh)       # keep one mesh for the mirror
             self._dev_inv_perm = self._put(inv, mesh)
         return self._dev_inv_perm
@@ -631,26 +636,24 @@ def query_index_sharded(sindex: ShardedZoneMapIndex, boxes: BoxSet,
 def _shard_call(local, mesh, n_sharded: int, n_repl: int):
     """Lift a per-shard ``local`` to a function over stacked [S, ...]
     arrays: vmap over the leading axis when ``mesh`` is None (the
-    single-device fallback — same math, same bits), else shard_map over
-    the mesh's "shards" axis via the repro.compat shim (jax 0.4.x keeps
-    working). ``local`` sees unbatched per-shard arrays either way;
-    scalars come back as [S]. The first ``n_sharded`` arguments are
-    stacked/sharded, the rest replicated."""
+    single-device fallback — same math, same bits), else jax.shard_map
+    over the mesh's "shards" axis. ``local`` sees unbatched per-shard
+    arrays either way; scalars come back as [S]. The first ``n_sharded``
+    arguments are stacked/sharded, the rest replicated."""
     if mesh is None:
         return jax.vmap(local, in_axes=(0,) * n_sharded + (None,) * n_repl)
 
     from jax.sharding import PartitionSpec as P
-
-    from repro.compat import shard_map
 
     def wrapped(*args):
         sh = [a[0] for a in args[:n_sharded]]     # strip the size-1 axis
         out = local(*sh, *args[n_sharded:])
         return tuple(jnp.asarray(o)[None] for o in out)
 
-    return shard_map(wrapped, mesh=mesh,
-                     in_specs=(P("shards"),) * n_sharded + (P(),) * n_repl,
-                     out_specs=P("shards"), check_vma=False)
+    return jax.shard_map(wrapped, mesh=mesh,
+                         in_specs=(P("shards"),) * n_sharded
+                         + (P(),) * n_repl,
+                         out_specs=P("shards"), check_vma=False)
 
 
 # the jit-builder caches are BOUNDED: their keys hold Mesh references,
@@ -1118,8 +1121,6 @@ def distributed_query(index_rows: jax.Array, zlo: jax.Array, zhi: jax.Array,
     on a pod (queries fan out, id lists gather back)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     def local(rows, lo_z, hi_z, lo_b, hi_b):
         m = kref.zone_prune_ref(lo_z, hi_z, lo_b, hi_b).any(1)     # [nb_local]
         flat = rows.reshape(-1, rows.shape[-1])
@@ -1127,7 +1128,7 @@ def distributed_query(index_rows: jax.Array, zlo: jax.Array, zhi: jax.Array,
         keep = jnp.repeat(m, block)
         return jnp.where(keep, counts, 0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P(), P()),
         out_specs=P("data"),
@@ -1174,9 +1175,7 @@ def distributed_query_pruned(index_rows: jax.Array, zlo: jax.Array,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         pruned_local_step(block, capacity), mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P(), P()),
         out_specs=P("data"),

@@ -58,20 +58,18 @@ def make_index_query_step(mesh, block: int, capacity: int):
     core/index.pruned_local_step (NOT re-implemented here), so the HLO
     this dry-run lowers at paper scale is byte-for-byte the production
     step distributed_query_pruned shard_maps."""
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.index import pruned_local_step
 
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data", "model"))
     spec = P(dp)
-    return shard_map(pruned_local_step(block, capacity), mesh=mesh,
-                     in_specs=(spec, spec, spec, P(), P()),
-                     out_specs=spec, check_vma=False)
+    return jax.shard_map(pruned_local_step(block, capacity), mesh=mesh,
+                         in_specs=(spec, spec, spec, P(), P()),
+                         out_specs=spec, check_vma=False)
 
 
 def make_full_scan_step(mesh, block: int):
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import ref as kref
@@ -82,8 +80,8 @@ def make_full_scan_step(mesh, block: int):
 
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data", "model"))
     spec = P(dp)
-    return shard_map(local, mesh=mesh, in_specs=(spec, P(), P()),
-                     out_specs=spec, check_vma=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, P(), P()),
+                         out_specs=spec, check_vma=False)
 
 
 def run_variant(variant: str, *, n_rows: int = PAPER_ROWS, d_sub: int = 6,

@@ -14,6 +14,7 @@ import numpy as np
 from repro.core.engine import MODELS, SearchEngine
 from repro.data.synthetic import (CLASS_IDS, PatchDatasetConfig,
                                   generate_patches, handcrafted_features)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import QueryRequest, QueryServer
 
 
@@ -29,6 +30,7 @@ def main() -> int:
     ap.add_argument("--subset-dim", type=int, default=6)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"[serve] generating {args.rows} synthetic patches ...")
     data = generate_patches(PatchDatasetConfig(
@@ -50,6 +52,7 @@ def main() -> int:
     neg_pool = np.nonzero(labels != pos_cls)[0]
 
     pending = []
+    failed = 0
     t0 = time.perf_counter()
     for q in range(args.queries):
         pos = rng.choice(pos_pool, args.labels, replace=False)
@@ -62,6 +65,7 @@ def main() -> int:
             hit = (labels[r.ids] == pos_cls).mean() if r.n_found else 0.0
             print(f"  q{q}: {r.summary()}  precision={hit:.2f}")
         else:
+            failed += 1
             print(f"  q{q}: ERROR {resp.error}")
     dt = time.perf_counter() - t0
     server.close()
@@ -69,7 +73,8 @@ def main() -> int:
     print(f"[serve] {s['served']} queries in {dt:.2f}s "
           f"(mean latency {1e3 * s['mean_latency_s']:.1f} ms, "
           f"errors {s['errors']})")
-    return 0
+    # a failed query fails the run: callers script this launcher
+    return 1 if failed or s["errors"] else 0
 
 
 if __name__ == "__main__":

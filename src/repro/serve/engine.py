@@ -30,6 +30,7 @@ Production notes:
 from __future__ import annotations
 
 import copy
+import logging
 import queue
 import threading
 import time
@@ -47,6 +48,9 @@ from repro.obs import trace as obs_trace
 from repro.serve.cache import ResultCache, request_key
 from repro.serve.policy import (AdmissionQueue, Overloaded, RateLimited,
                                 RetryPolicy, ServerClosed, TokenBucket)
+
+
+log = logging.getLogger(__name__)
 
 
 def _error_type(exc: BaseException) -> str:
@@ -213,6 +217,10 @@ class QueryServer:
                       "expired_in_queue": 0, "evicted": 0,
                       "shutdown_unserved": 0, "submit_faults": 0,
                       "retries": 0, "batch_fallbacks": 0,
+                      # windows whose batched device fit failed and were
+                      # refitted on the numpy trainer (exact, but off the
+                      # device — DESIGN.md §10)
+                      "fit_fallbacks": 0,
                       "compaction_errors": 0, "compaction_retries": 0,
                       "degraded_windows": 0,
                       "checkpoints": 0, "checkpoint_errors": 0,
@@ -671,6 +679,8 @@ class QueryServer:
             # sequential fallback: each request retried alone. The failed
             # batch attempt's wall time was REAL latency for every
             # request in the window — bill it, don't drop it.
+            log.warning("batch window of %d failed; answering each "
+                        "request alone", len(reqs), exc_info=True)
             self._bump("batch_fallbacks")
             wasted = time.perf_counter() - t0
             resps = [self.handle(r) for r in reqs]
@@ -683,8 +693,8 @@ class QueryServer:
         upd: Dict = {"batches": 1, "batched_queries": len(reqs),
                      "served": len(reqs), "errors": 0, "latency_sum": 0.0,
                      "fit_s_sum": 0.0, "host_bytes": 0,
-                     "sharded_queries": 0}
-        batch_bytes_counted = False
+                     "sharded_queries": 0, "fit_fallbacks": 0}
+        batch_counted = False
         for r, key, out in zip(reqs, keys, outs):
             expired = None
             if not isinstance(out, Exception):
@@ -708,10 +718,12 @@ class QueryServer:
                 # batch_* aggregates describe the SHARED device phase —
                 # count them once per batch, not once per request
                 if "batch_host_bytes_transferred" in out.stats:
-                    if not batch_bytes_counted:
+                    if not batch_counted:
                         upd["host_bytes"] += out.stats[
                             "batch_host_bytes_transferred"]
-                        batch_bytes_counted = True
+                        upd["fit_fallbacks"] += out.stats.get(
+                            "batch_fit_fallbacks", 0)
+                        batch_counted = True
                 else:
                     upd["host_bytes"] += out.stats.get(
                         "host_bytes_transferred", 0)

@@ -462,6 +462,7 @@ class HttpFrontEnd:
                 "n_found": res.n_found,
                 "train_time_s": res.train_time_s,
                 "query_time_s": res.query_time_s,
+                "fit_path": res.stats.get("fit_path"),
                 "latency_ms": round(1e3 * resp.latency_s, 3),
                 "cache": resp.info.get("cache", "miss"),
             })
@@ -526,6 +527,7 @@ def main(argv=None) -> None:   # pragma: no cover - exercised manually
     from repro.core.engine import SearchEngine
     from repro.data.synthetic import (PatchDatasetConfig, generate_patches,
                                       handcrafted_features)
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve.cache import ResultCache
 
     ap = argparse.ArgumentParser(
@@ -537,6 +539,7 @@ def main(argv=None) -> None:   # pragma: no cover - exercised manually
     ap.add_argument("--queue-depth", type=int, default=64)
     ap.add_argument("--deadline-s", type=float, default=10.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     data = generate_patches(PatchDatasetConfig(n_patches=args.n, seed=0))
     feats = handcrafted_features(data["images"])

@@ -81,8 +81,6 @@ def compressed_cross_pod_mean(grads: PyTree, ef: PyTree, mesh,
     values, with the per-pod residual folded into error feedback."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     comp = Int8ErrorFeedback()
     qtree, ef = comp.compress(grads, ef)
 
@@ -96,9 +94,9 @@ def compressed_cross_pod_mean(grads: PyTree, ef: PyTree, mesh,
             del qsum
             return vsum / n
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(), P()), out_specs=P(),
-                       check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(), P()), out_specs=P(),
+                           check_vma=False)
         return fn(z.q, z.scale)
 
     out = jax.tree.map(reduce_leaf, qtree,

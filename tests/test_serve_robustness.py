@@ -463,6 +463,31 @@ def test_batch_fallback_bills_wasted_wall(base_x):
         sum(r.latency_s for r in resps), rel=1e-6)
 
 
+def test_device_fit_failure_is_counted(base_x):
+    """A window whose batched device fit raises is refitted request by
+    request on the numpy trainer: answers stay exact, and the fallback
+    shows in fit_fallbacks and in each result's fit_path."""
+    eng = SearchEngine(base_x, **ENG)
+
+    def refuse(*args, **kwargs):
+        raise RuntimeError("device fit refused")
+    eng._fit_boxes_batched = refuse
+    srv = QueryServer(eng)
+    pos, neg = _labels()
+    resps = srv.handle_batch([QueryRequest(i, pos, neg, model=m)
+                              for i, m in enumerate(("dbranch", "dbens"))])
+    assert all(r.ok for r in resps)
+    assert [r.result.stats["fit_path"] for r in resps] == ["numpy"] * 2
+    summ = srv.summary()
+    assert summ["fit_fallbacks"] == 1
+    assert summ["batch_fallbacks"] == 0 and summ["errors"] == 0
+    clean = SearchEngine(base_x, **ENG)
+    for r, m in zip(resps, ("dbranch", "dbens")):
+        want = clean.query(pos, neg, model=m)
+        np.testing.assert_array_equal(r.result.ids, want.ids)
+        np.testing.assert_array_equal(r.result.scores, want.scores)
+
+
 def test_batch_deadline_exceeded_short_circuits(base_x):
     eng = SearchEngine(base_x, **ENG)
     srv = QueryServer(eng)
