@@ -76,6 +76,13 @@ log = logging.getLogger(__name__)
 # sentinel: "no per-call override — use the engine default"
 _UNSET = object()
 
+# the obs layer stays stdlib-only: this JAX-importing layer installs its
+# profiler hooks — spans also enter a TraceAnnotation of their name, and
+# an executable built or loaded on a traced thread becomes a ``compile``
+# span of the request that paid for it (DESIGN.md §17)
+obs_trace.install_hooks(jax.profiler.TraceAnnotation,
+                        jax.monitoring.register_event_duration_secs_listener)
+
 
 @dataclass
 class QueryResult:
@@ -136,6 +143,40 @@ class SparseScores:
     @property
     def nbytes(self) -> int:
         return int(self.keys.nbytes) + int(self.vals.nbytes)
+
+
+class _DeviceRound:
+    """One launch round of a score loop (``SearchEngine._device_round``):
+    the launch loop runs inside it as a context — the ``dispatch`` span
+    and the ``jit_dispatch`` profile site — and ``sync`` then reads every
+    subset's stat vector in ONE batched device->host transfer — the
+    ``sync`` span and the ``device_sync`` site. Both are children of the
+    round's ``device_round`` span."""
+
+    __slots__ = ("_engine", "_span", "_t0")
+
+    def __init__(self, engine: "SearchEngine"):
+        self._engine = engine
+
+    def __enter__(self):
+        self._span = obs_trace.span("dispatch")
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is None:
+            obs_profile.record("jit_dispatch",
+                               time.perf_counter() - self._t0)
+        return self._span.__exit__(exc_type, exc, tb)
+
+    def sync(self, parts, agg: Dict) -> np.ndarray:
+        self._engine._fault("device_sync")
+        with obs_trace.span("sync"), obs_profile.profile("device_sync"):
+            out = np.asarray(jnp.stack(parts))
+        agg["n_host_syncs"] += 1
+        agg["host_bytes_transferred"] += int(out.nbytes)
+        return out
 
 
 class SearchEngine:
@@ -387,17 +428,19 @@ class SearchEngine:
         if self.faults is not None:
             self.faults.check(site)
 
-    def _round_checkpoint(self, deadline_s) -> None:
-        """Once per device launch round: the fused-query fault seam plus
-        the between-rounds deadline check — a request whose budget is
-        gone stops HERE instead of burning another round of device time
-        (rounds are the natural cancellation points; in-flight device
-        programs are not interruptible)."""
-        # trace seam too: closes the previous device_round span and
-        # opens the next on every ambient trace (no-op untraced)
+    def _device_round(self, deadline_s) -> "_DeviceRound":
+        """Open one device launch round of a score loop: closes the
+        previous ``device_round`` span and opens the next on every
+        ambient trace (no-op untraced), then the fused-query fault seam
+        and the between-rounds deadline check — a request whose budget
+        is gone stops HERE instead of burning another round of device
+        time (rounds are the natural cancellation points; in-flight
+        device programs are not interruptible). The returned round
+        times its launch loop as a context and does its one sync."""
         obs_trace.round_mark()
         self._fault("fused_query")
         check_deadline(deadline_s, "device query round")
+        return _DeviceRound(self)
 
     def invalidate_capacity_hints(self) -> int:
         """Drop every capacity hint (cold-start sizing resumes). The
@@ -542,48 +585,50 @@ class SearchEngine:
         (DESIGN.md §14): checked before the fit and between per-subset
         device rounds, raising a typed ``DeadlineExceeded`` instead of
         finishing work nobody is waiting for."""
-        _t_prep = time.perf_counter()
-        if model not in MODELS:
-            raise ValueError(f"unknown model {model!r}; choose from {MODELS}")
-        check_deadline(deadline_s, "fit")
-        mr = self.max_results if max_results is _UNSET else max_results
-        view = self._view()
-        pos_ids = np.asarray(list(pos_ids), np.int64)
-        neg_ids = np.asarray(list(neg_ids), np.int64)
-        xp, xn = view.x[pos_ids], view.x[neg_ids]
         # snapshot + label-row gather is real pre-fit wall: billed as its
-        # own span so traces account for >=90% of the request
-        obs_trace.add_span_active("prepare", _t_prep,
-                                  time.perf_counter() - _t_prep)
+        # own span so the root's children account for >=90% of the wall
+        with obs_trace.span("prepare"):
+            if model not in MODELS:
+                raise ValueError(
+                    f"unknown model {model!r}; choose from {MODELS}")
+            check_deadline(deadline_s, "fit")
+            mr = self.max_results if max_results is _UNSET else max_results
+            view = self._view()
+            pos_ids = np.asarray(list(pos_ids), np.int64)
+            neg_ids = np.asarray(list(neg_ids), np.int64)
+            xp, xn = view.x[pos_ids], view.x[neg_ids]
 
         t0 = time.perf_counter()
-        if model in ("dbranch", "dbens"):
-            if self.use_jax_fit and self.use_fused:
-                # device fit, device boxes: only the [2, G] winner meta
-                # crosses to the host (DESIGN.md §10)
-                lo_c, hi_c, entries = self._fit_boxes_batched(
-                    [(model, xp, xn, n_models, seed)], max_depth=max_depth,
-                    return_device=True, frange=view.frange)
-                if isinstance(entries[0], Exception):
-                    raise entries[0]
-                boxes = ("device", lo_c, hi_c, entries[0])
-            else:
-                # the non-fused engine is the all-oracle configuration:
-                # host inference AND the numpy trainer (DESIGN.md §10)
-                boxes = self._fit_boxes(model, xp, xn, max_depth=max_depth,
-                                        n_models=n_models, seed=seed,
-                                        use_jax=False, frange=view.frange)
-        elif model == "dtree":
-            xtr = np.concatenate([xp, xn])
-            ytr = np.concatenate([np.ones(len(xp)), np.zeros(len(xn))])
-            tree = fit_decision_tree(xtr, ytr, max_depth=max_depth)
-        elif model == "rforest":
-            xtr = np.concatenate([xp, xn])
-            ytr = np.concatenate([np.ones(len(xp)), np.zeros(len(xn))])
-            forest = fit_random_forest(xtr, ytr, n_trees=n_models,
-                                       max_depth=max_depth, seed=seed)
+        with obs_trace.span("fit"):
+            if model in ("dbranch", "dbens"):
+                if self.use_jax_fit and self.use_fused:
+                    # device fit, device boxes: only the [2, G] winner
+                    # meta crosses to the host (DESIGN.md §10)
+                    lo_c, hi_c, entries = self._fit_boxes_batched(
+                        [(model, xp, xn, n_models, seed)],
+                        max_depth=max_depth, return_device=True,
+                        frange=view.frange)
+                    if isinstance(entries[0], Exception):
+                        raise entries[0]
+                    boxes = ("device", lo_c, hi_c, entries[0])
+                else:
+                    # the non-fused engine is the all-oracle
+                    # configuration: host inference AND the numpy trainer
+                    # (DESIGN.md §10)
+                    boxes = self._fit_boxes(
+                        model, xp, xn, max_depth=max_depth,
+                        n_models=n_models, seed=seed, use_jax=False,
+                        frange=view.frange)
+            elif model == "dtree":
+                xtr = np.concatenate([xp, xn])
+                ytr = np.concatenate([np.ones(len(xp)), np.zeros(len(xn))])
+                tree = fit_decision_tree(xtr, ytr, max_depth=max_depth)
+            elif model == "rforest":
+                xtr = np.concatenate([xp, xn])
+                ytr = np.concatenate([np.ones(len(xp)), np.zeros(len(xn))])
+                forest = fit_random_forest(xtr, ytr, n_trees=n_models,
+                                           max_depth=max_depth, seed=seed)
         t_fit = time.perf_counter() - t0
-        obs_trace.add_span_active("fit", t0, t_fit)
 
         # ---- inference + ranking --------------------------------------
         t0 = time.perf_counter()
@@ -934,13 +979,19 @@ class SearchEngine:
     def _device_scores(self, jobs, nq: int, view: _EngineView,
                        deadline_s=None):
         """Mode dispatch for the score accumulation, under a trace
-        round scope: each ``_round_checkpoint`` inside becomes one
+        round scope: each ``_device_round`` inside becomes one
         ``device_round`` span on every ambient trace (including
         overflow-retry rounds — the retries are visible per attempt).
         The scope is a shared no-op when nothing is attached."""
-        with obs_trace.round_scope():
-            return self._device_scores_impl(jobs, nq, view,
-                                            deadline_s=deadline_s)
+        with obs_trace.round_scope() as scope:
+            scores, agg = self._device_scores_impl(jobs, nq, view,
+                                                   deadline_s=deadline_s)
+            # the window's own counters, on the last round: the slow-
+            # query log says why a request was slow
+            scope.set(n_host_syncs=agg["n_host_syncs"],
+                      retried_subsets=agg["retried_subsets"],
+                      blocks_touched=agg["blocks_touched"])
+            return scores, agg
 
     def _device_scores_impl(self, jobs, nq: int, view: _EngineView,
                             deadline_s=None):
@@ -981,29 +1032,23 @@ class SearchEngine:
                                            merged.n_boxes))
                    for sid, merged, owner in jobs]
         while pending:
-            self._round_checkpoint(deadline_s)
-            launched = []
-            _t_disp = time.perf_counter()
-            for sid, merged, owner, cap in pending:
-                index = view.indexes[sid]
-                rows3, zlo, zhi = index.device_arrays()
-                lo, hi, owner_p = pad_boxes(merged.lo, merged.hi, owner)
-                onehot = jnp.asarray(
-                    (owner_p[:, None] == np.arange(nq)[None]
-                     ).astype(np.float32))
-                counts, cand, n_hit = kops.fused_query(
-                    rows3, zlo, zhi, jnp.asarray(lo), jnp.asarray(hi),
-                    onehot, capacity=cap, use_pallas=self.use_pallas)
-                launched.append((sid, merged, owner, cap, counts, cand,
-                                 n_hit))
+            with self._device_round(deadline_s) as rnd:
+                launched = []
+                for sid, merged, owner, cap in pending:
+                    index = view.indexes[sid]
+                    rows3, zlo, zhi = index.device_arrays()
+                    lo, hi, owner_p = pad_boxes(merged.lo, merged.hi,
+                                                owner)
+                    onehot = jnp.asarray(
+                        (owner_p[:, None] == np.arange(nq)[None]
+                         ).astype(np.float32))
+                    counts, cand, n_hit = kops.fused_query(
+                        rows3, zlo, zhi, jnp.asarray(lo), jnp.asarray(hi),
+                        onehot, capacity=cap, use_pallas=self.use_pallas)
+                    launched.append((sid, merged, owner, cap, counts, cand,
+                                     n_hit))
             # ONE batched sync covers the whole round's overflow checks
-            obs_profile.record("jit_dispatch",
-                               time.perf_counter() - _t_disp)
-            self._fault("device_sync")
-            with obs_profile.profile("device_sync"):
-                n_hits = np.asarray(jnp.stack([l[6] for l in launched]))
-            agg["n_host_syncs"] += 1
-            agg["host_bytes_transferred"] += int(n_hits.nbytes)
+            n_hits = rnd.sync([l[6] for l in launched], agg)
             pending = []
             for (sid, merged, owner, cap, counts, cand, _), nh in zip(
                     launched, n_hits):
@@ -1060,28 +1105,22 @@ class SearchEngine:
                                            merged.n_boxes))
                    for sid, merged, owner in jobs]
         while pending:
-            self._round_checkpoint(deadline_s)
-            launched = []
-            _t_disp = time.perf_counter()
-            for sid, merged, owner, cap in pending:
-                sindex = self.indexes[sid]
-                lo, hi, owner_p = pad_boxes(merged.lo, merged.hi, owner)
-                onehot = jnp.asarray(
-                    (owner_p[:, None] == np.arange(nq)[None]
-                     ).astype(np.float32))
-                scores, st3 = sharded_query_accumulate(
-                    sindex, scores, jnp.asarray(lo), jnp.asarray(hi),
-                    onehot, capacity=cap, mesh=self.shard_mesh,
-                    use_pallas=self.use_pallas)
-                launched.append((sid, merged, owner, cap, st3))
+            with self._device_round(deadline_s) as rnd:
+                launched = []
+                for sid, merged, owner, cap in pending:
+                    sindex = self.indexes[sid]
+                    lo, hi, owner_p = pad_boxes(merged.lo, merged.hi,
+                                                owner)
+                    onehot = jnp.asarray(
+                        (owner_p[:, None] == np.arange(nq)[None]
+                         ).astype(np.float32))
+                    scores, st3 = sharded_query_accumulate(
+                        sindex, scores, jnp.asarray(lo), jnp.asarray(hi),
+                        onehot, capacity=cap, mesh=self.shard_mesh,
+                        use_pallas=self.use_pallas)
+                    launched.append((sid, merged, owner, cap, st3))
             # ONE batched sync, [3] ints per subset — flat in shard count
-            obs_profile.record("jit_dispatch",
-                               time.perf_counter() - _t_disp)
-            self._fault("device_sync")
-            with obs_profile.profile("device_sync"):
-                hit_stats = np.asarray(jnp.stack([l[4] for l in launched]))
-            agg["n_host_syncs"] += 1
-            agg["host_bytes_transferred"] += int(hit_stats.nbytes)
+            hit_stats = rnd.sync([l[4] for l in launched], agg)
             pending = []
             for (sid, merged, owner, cap, _), st in zip(launched,
                                                         hit_stats):
@@ -1133,28 +1172,22 @@ class SearchEngine:
                                            geom=view.geom))
                    for sid, merged, owner in jobs]
         while pending:
-            self._round_checkpoint(deadline_s)
-            launched = []
-            _t_disp = time.perf_counter()
-            for sid, merged, owner, cap in pending:
-                segx = view.indexes[sid]
-                lo, hi, owner_p = pad_boxes(merged.lo, merged.hi, owner)
-                onehot = jnp.asarray(
-                    (owner_p[:, None] == np.arange(nq)[None]
-                     ).astype(np.float32))
-                scores, stvec = segmented_query_accumulate(
-                    segx, scores, jnp.asarray(lo), jnp.asarray(hi),
-                    onehot, view.valid, capacity=cap,
-                    use_pallas=self.use_pallas)
-                launched.append((sid, merged, owner, cap, stvec))
+            with self._device_round(deadline_s) as rnd:
+                launched = []
+                for sid, merged, owner, cap in pending:
+                    segx = view.indexes[sid]
+                    lo, hi, owner_p = pad_boxes(merged.lo, merged.hi,
+                                                owner)
+                    onehot = jnp.asarray(
+                        (owner_p[:, None] == np.arange(nq)[None]
+                         ).astype(np.float32))
+                    scores, stvec = segmented_query_accumulate(
+                        segx, scores, jnp.asarray(lo), jnp.asarray(hi),
+                        onehot, view.valid, capacity=cap,
+                        use_pallas=self.use_pallas)
+                    launched.append((sid, merged, owner, cap, stvec))
             # ONE batched sync: [J, 1 + S] int32 for the whole round
-            obs_profile.record("jit_dispatch",
-                               time.perf_counter() - _t_disp)
-            self._fault("device_sync")
-            with obs_profile.profile("device_sync"):
-                stvecs = np.asarray(jnp.stack([l[4] for l in launched]))
-            agg["n_host_syncs"] += 1
-            agg["host_bytes_transferred"] += int(stvecs.nbytes)
+            stvecs = rnd.sync([l[4] for l in launched], agg)
             pending = []
             for (sid, merged, owner, cap, _), st in zip(launched, stvecs):
                 segx = view.indexes[sid]
@@ -1236,38 +1269,34 @@ class SearchEngine:
                                            merged.n_boxes, geom=geom))
                    for sid, merged, owner in jobs]
         while pending:
-            self._round_checkpoint(deadline_s)
-            launched, round_parts, round_rcaps = [], [], []
-            _t_disp = time.perf_counter()
-            for sid, merged, owner, cap in pending:
-                index = view.indexes[sid]
-                lo, hi, owner_p = pad_boxes(merged.lo, merged.hi, owner)
-                onehot = jnp.asarray(
-                    (owner_p[:, None] == np.arange(nq)[None]
-                     ).astype(np.float32))
-                lo_d, hi_d = jnp.asarray(lo), jnp.asarray(hi)
-                if live:
-                    probe = segmented_sparse_probe(
-                        index, lo_d, hi_d, onehot, view.valid,
-                        capacity=cap, use_pallas=self.use_pallas)
-                elif sharded:
-                    probe = sharded_sparse_probe(
-                        index, lo_d, hi_d, onehot, capacity=cap,
-                        mesh=self.shard_mesh, use_pallas=self.use_pallas)
-                else:
-                    probe = sparse_probe(index, lo_d, hi_d, onehot,
-                                         capacity=cap,
-                                         use_pallas=self.use_pallas)
-                launched.append((sid, merged, owner, cap) + probe)
+            round_parts, round_rcaps = [], []
+            with self._device_round(deadline_s) as rnd:
+                launched = []
+                for sid, merged, owner, cap in pending:
+                    index = view.indexes[sid]
+                    lo, hi, owner_p = pad_boxes(merged.lo, merged.hi,
+                                                owner)
+                    onehot = jnp.asarray(
+                        (owner_p[:, None] == np.arange(nq)[None]
+                         ).astype(np.float32))
+                    lo_d, hi_d = jnp.asarray(lo), jnp.asarray(hi)
+                    if live:
+                        probe = segmented_sparse_probe(
+                            index, lo_d, hi_d, onehot, view.valid,
+                            capacity=cap, use_pallas=self.use_pallas)
+                    elif sharded:
+                        probe = sharded_sparse_probe(
+                            index, lo_d, hi_d, onehot, capacity=cap,
+                            mesh=self.shard_mesh,
+                            use_pallas=self.use_pallas)
+                    else:
+                        probe = sparse_probe(index, lo_d, hi_d, onehot,
+                                             capacity=cap,
+                                             use_pallas=self.use_pallas)
+                    launched.append((sid, merged, owner, cap) + probe)
             # ONE batched sync: a FIXED-width int vector per subset —
             # flat in shard count, exactly the dense cadence
-            obs_profile.record("jit_dispatch",
-                               time.perf_counter() - _t_disp)
-            self._fault("device_sync")
-            with obs_profile.profile("device_sync"):
-                stvecs = np.asarray(jnp.stack([l[7] for l in launched]))
-            agg["n_host_syncs"] += 1
-            agg["host_bytes_transferred"] += int(stvecs.nbytes)
+            stvecs = rnd.sync([l[7] for l in launched], agg)
             pending = []
             for (sid, merged, owner, cap, counts, gids, ok, _), st in zip(
                     launched, stvecs):
@@ -1374,27 +1403,21 @@ class SearchEngine:
                                            merged.n_boxes))
                    for sid, merged, owner in jobs]
         while pending:
-            self._round_checkpoint(deadline_s)
-            launched = []
-            _t_disp = time.perf_counter()
-            for sid, merged, owner, cap in pending:
-                index = view.indexes[sid]
-                lo, hi, owner_p = pad_boxes(merged.lo, merged.hi, owner)
-                onehot = jnp.asarray(
-                    (owner_p[:, None] == np.arange(nq)[None]
-                     ).astype(np.float32))
-                lo_d, hi_d = jnp.asarray(lo), jnp.asarray(hi)
-                gids, cmask, st = quantized_probe(index, lo_d, hi_d,
-                                                  capacity=cap)
-                launched.append((sid, merged, owner, cap, gids, cmask,
-                                 st, lo_d, hi_d, onehot))
-            obs_profile.record("jit_dispatch",
-                               time.perf_counter() - _t_disp)
-            self._fault("device_sync")
-            with obs_profile.profile("device_sync"):
-                stvecs = np.asarray(jnp.stack([l[6] for l in launched]))
-            agg["n_host_syncs"] += 1
-            agg["host_bytes_transferred"] += int(stvecs.nbytes)
+            with self._device_round(deadline_s) as rnd:
+                launched = []
+                for sid, merged, owner, cap in pending:
+                    index = view.indexes[sid]
+                    lo, hi, owner_p = pad_boxes(merged.lo, merged.hi,
+                                                owner)
+                    onehot = jnp.asarray(
+                        (owner_p[:, None] == np.arange(nq)[None]
+                         ).astype(np.float32))
+                    lo_d, hi_d = jnp.asarray(lo), jnp.asarray(hi)
+                    gids, cmask, st = quantized_probe(index, lo_d, hi_d,
+                                                      capacity=cap)
+                    launched.append((sid, merged, owner, cap, gids, cmask,
+                                     st, lo_d, hi_d, onehot))
+            stvecs = rnd.sync([l[6] for l in launched], agg)
             pending = []
             for (sid, merged, owner, cap, gids, cmask, _, lo_d, hi_d,
                  onehot), st in zip(launched, stvecs):
@@ -1544,38 +1567,37 @@ class SearchEngine:
             ids, scores = self._rank(counts, pos_ids, neg_ids,
                                      include_training)
             return ids, scores, stats    # query() applies the mr cut
-        _t_prep = time.perf_counter()
-        if isinstance(boxsets, tuple) and boxsets[0] == "device":
-            _, lo_c, hi_c, ent = boxsets
-            jobs, bound = self._make_jobs_flat(
-                [(lo_c, hi_c, g, sid, cnt, 0) for g, sid, cnt in ent], 1)
-        else:
-            jobs, bound = self._make_jobs([(bs, 0) for bs in boxsets], 1)
         # job assembly (per-subset grouping, device slicing) sits between
         # fit and the first device round: billed so it never reads as an
         # unexplained gap in the trace
-        obs_trace.add_span_active("prepare", _t_prep,
-                                  time.perf_counter() - _t_prep,
-                                  {"jobs": len(jobs)})
+        with obs_trace.span("prepare") as sp:
+            if isinstance(boxsets, tuple) and boxsets[0] == "device":
+                _, lo_c, hi_c, ent = boxsets
+                jobs, bound = self._make_jobs_flat(
+                    [(lo_c, hi_c, g, sid, cnt, 0) for g, sid, cnt in ent],
+                    1)
+            else:
+                jobs, bound = self._make_jobs([(bs, 0) for bs in boxsets],
+                                              1)
+            sp.set(jobs=len(jobs))
         scores_dev, stats = self._device_scores(jobs, 1, view,
                                                 deadline_s=deadline_s)
-        _t_rank = time.perf_counter()
-        if mr is None:
-            counts = self._scores_to_host(scores_dev, view)[:, 0]
-            # sparse buffers cross as tiles: price what actually moved
-            stats["host_bytes_transferred"] += (
-                scores_dev.nbytes if isinstance(scores_dev, SparseScores)
-                else int(counts.nbytes))
-            ids, scores = self._rank(counts, pos_ids, neg_ids,
-                                     include_training)
-        else:
-            ranked, hb = self._rank_device(
-                scores_dev, [(pos_ids, neg_ids, include_training)], mr,
-                bound, view)
-            stats["host_bytes_transferred"] += hb
-            ids, scores = ranked[0]
-        obs_trace.add_span_active("rank", _t_rank,
-                                  time.perf_counter() - _t_rank)
+        with obs_trace.span("rank"):
+            if mr is None:
+                counts = self._scores_to_host(scores_dev, view)[:, 0]
+                # sparse buffers cross as tiles: price what actually moved
+                stats["host_bytes_transferred"] += (
+                    scores_dev.nbytes
+                    if isinstance(scores_dev, SparseScores)
+                    else int(counts.nbytes))
+                ids, scores = self._rank(counts, pos_ids, neg_ids,
+                                         include_training)
+            else:
+                ranked, hb = self._rank_device(
+                    scores_dev, [(pos_ids, neg_ids, include_training)], mr,
+                    bound, view)
+                stats["host_bytes_transferred"] += hb
+                ids, scores = ranked[0]
         return ids, scores, stats
 
     # ------------------------------------------------------------------
@@ -1708,124 +1730,125 @@ class SearchEngine:
         # window-wide device-fit failures that fell back to the numpy
         # trainer: answers stay exact, so only this count shows it
         fit_fallbacks = 0
-        if self.use_jax_fit:
-            # slot -> ("device", lo, hi, entries) or List[BoxSet] fallback
-            boxsets_by_slot: Dict[int, object] = {}
-            by_depth: Dict[int, List] = {}
-            for it in to_fit:
-                by_depth.setdefault(it[6], []).append(it)
-            for depth, items in by_depth.items():
-                try:
-                    lo_c, hi_c, entries = self._fit_boxes_batched(
-                        [(it[1], view.x[it[2]], view.x[it[3]], it[7], it[8])
-                         for it in items], max_depth=depth,
-                        return_device=True, frange=view.frange)
-                except Exception:  # noqa: BLE001 — degrade, don't die
-                    entries = None  # batch-wide failure: per-request oracle
-                    fit_fallbacks += 1
-                    log.warning("batched device fit failed for %d "
-                                "requests; refitting each on the numpy "
-                                "trainer", len(items), exc_info=True)
-                for j, it in enumerate(items):
-                    if entries is not None and not isinstance(
-                            entries[j], Exception):
-                        boxsets_by_slot[it[0]] = ("device", lo_c, hi_c,
-                                                  entries[j])
-                        continue
-                    # this request failed the device fit (or the whole
-                    # window did): retry it alone on the numpy oracle so
-                    # one bad label set never drags the batch down
+        # slot -> ("device", lo, hi, entries) or List[BoxSet] fallback
+        boxsets_by_slot: Dict[int, object] = {}
+        # the batched fit is one shared device phase: every trace in the
+        # window carries the same fit span (shared-cost attribution)
+        with obs_trace.span("fit", {"batch": len(to_fit)}):
+            if self.use_jax_fit:
+                by_depth: Dict[int, List] = {}
+                for it in to_fit:
+                    by_depth.setdefault(it[6], []).append(it)
+                for depth, items in by_depth.items():
                     try:
-                        boxsets_by_slot[it[0]] = self._fit_boxes(
+                        lo_c, hi_c, entries = self._fit_boxes_batched(
+                            [(it[1], view.x[it[2]], view.x[it[3]], it[7],
+                              it[8]) for it in items], max_depth=depth,
+                            return_device=True, frange=view.frange)
+                    except Exception:  # noqa: BLE001 — degrade, don't die
+                        entries = None  # batch-wide: per-request oracle
+                        fit_fallbacks += 1
+                        log.warning("batched device fit failed for %d "
+                                    "requests; refitting each on the "
+                                    "numpy trainer", len(items),
+                                    exc_info=True)
+                    for j, it in enumerate(items):
+                        if entries is not None and not isinstance(
+                                entries[j], Exception):
+                            boxsets_by_slot[it[0]] = ("device", lo_c, hi_c,
+                                                      entries[j])
+                            continue
+                        # this request failed the device fit (or the
+                        # whole window did): retry it alone on the numpy
+                        # oracle so one bad label set never drags the
+                        # batch down
+                        try:
+                            boxsets_by_slot[it[0]] = self._fit_boxes(
+                                it[1], view.x[it[2]], view.x[it[3]],
+                                max_depth=it[6], n_models=it[7],
+                                seed=it[8], use_jax=False,
+                                frange=view.frange)
+                        except Exception as e:  # noqa: BLE001
+                            results[it[0]] = e
+            else:
+                for it in to_fit:
+                    t1 = time.perf_counter()
+                    try:
+                        boxsets = self._fit_boxes(
                             it[1], view.x[it[2]], view.x[it[3]],
                             max_depth=it[6], n_models=it[7], seed=it[8],
-                            use_jax=False, frange=view.frange)
+                            frange=view.frange)
                     except Exception as e:  # noqa: BLE001
                         results[it[0]] = e
-            fit_wall = time.perf_counter() - t0
+                        continue
+                    fitted.append((it[0], it[1], boxsets, it[2], it[3],
+                                   it[4], it[5], time.perf_counter() - t1))
+        fit_wall = time.perf_counter() - t0
+        if self.use_jax_fit:
             # the fit is a shared device phase; bill it evenly
             share = fit_wall / max(len(boxsets_by_slot), 1)
             for it in to_fit:
                 if it[0] in boxsets_by_slot:
                     fitted.append((it[0], it[1], boxsets_by_slot[it[0]],
                                    it[2], it[3], it[4], it[5], share))
-        else:
-            for it in to_fit:
-                t1 = time.perf_counter()
-                try:
-                    boxsets = self._fit_boxes(
-                        it[1], view.x[it[2]], view.x[it[3]],
-                        max_depth=it[6], n_models=it[7], seed=it[8],
-                        frange=view.frange)
-                except Exception as e:  # noqa: BLE001
-                    results[it[0]] = e
-                    continue
-                fitted.append((it[0], it[1], boxsets, it[2], it[3], it[4],
-                               it[5], time.perf_counter() - t1))
-            fit_wall = time.perf_counter() - t0
-        # the batched fit is one shared device phase: every trace in the
-        # window carries the same fit span (shared-cost attribution)
-        obs_trace.add_span_active("fit", t0, fit_wall,
-                                  {"batch": len(to_fit)})
         if not fitted:
             return results
 
         # ---- ONE fused device call per subset, ONE deferred sync -------
         t0 = time.perf_counter()
         nq = len(fitted)
-        # device-fit requests contribute (winner-array, row) parts and
-        # never touch the host; oracle-fit (or fallback) requests
-        # contribute classic BoxSets — both merge into the same jobs
-        flat_parts, box_pairs = [], []
-        for q, (_, _, boxes, *_r) in enumerate(fitted):
-            if isinstance(boxes, tuple) and boxes[0] == "device":
-                flat_parts += [(boxes[1], boxes[2], g, sid, cnt, q)
-                               for g, sid, cnt in boxes[3]]
-            else:
-                box_pairs += [(bs, q) for bs in boxes]
-        jobs, bound = [], 0
-        if flat_parts:
-            jobs, bound = self._make_jobs_flat(flat_parts, nq)
-        if box_pairs:
-            j2, b2 = self._make_jobs(box_pairs, nq)
-            # a request's boxes live entirely in one form, so per-query
-            # score bounds combine by max
-            jobs, bound = jobs + j2, max(bound, b2)
         # shared assembly wall, same attribution rule as the fit span
-        obs_trace.add_span_active("prepare", t0,
-                                  time.perf_counter() - t0,
-                                  {"jobs": len(jobs)})
+        with obs_trace.span("prepare") as sp:
+            # device-fit requests contribute (winner-array, row) parts
+            # and never touch the host; oracle-fit (or fallback) requests
+            # contribute classic BoxSets — both merge into the same jobs
+            flat_parts, box_pairs = [], []
+            for q, (_, _, boxes, *_r) in enumerate(fitted):
+                if isinstance(boxes, tuple) and boxes[0] == "device":
+                    flat_parts += [(boxes[1], boxes[2], g, sid, cnt, q)
+                                   for g, sid, cnt in boxes[3]]
+                else:
+                    box_pairs += [(bs, q) for bs in boxes]
+            jobs, bound = [], 0
+            if flat_parts:
+                jobs, bound = self._make_jobs_flat(flat_parts, nq)
+            if box_pairs:
+                j2, b2 = self._make_jobs(box_pairs, nq)
+                # a request's boxes live entirely in one form, so
+                # per-query score bounds combine by max
+                jobs, bound = jobs + j2, max(bound, b2)
+            sp.set(jobs=len(jobs))
         scores_dev, agg = self._device_scores(jobs, nq, view,
                                               deadline_s=deadline_s)
 
         # ---- ranking ---------------------------------------------------
-        _t_rank = time.perf_counter()
-        mrs = [f[6] for f in fitted]
-        if all(m is not None for m in mrs):
-            masks = [(pos, neg, incl)
-                     for (_, _, _, pos, neg, incl, _, _) in fitted]
-            ranked, hb = self._rank_device(scores_dev, masks, max(mrs),
-                                           bound, view)
-            agg["host_bytes_transferred"] += hb
-            ranked = [(ids[:m], sc[:m]) for (ids, sc), m in zip(ranked, mrs)]
-        else:
-            # any full-result request forces the score buffer to the host
-            # ONCE; ranking shares the oracle so truncated requests still
-            # see the exact device-ranking prefix
-            counts = np.ascontiguousarray(
-                self._scores_to_host(scores_dev, view).T)
-            # sparse buffers cross as tiles: price what actually moved
-            agg["host_bytes_transferred"] += (
-                scores_dev.nbytes if isinstance(scores_dev, SparseScores)
-                else int(counts.nbytes))
-            ranked = []
-            for q, (_, _, _, pos, neg, incl, m, _) in enumerate(fitted):
-                ids, sc = self._rank(counts[q], pos, neg, incl)
-                if m is not None:
-                    ids, sc = ids[:m], sc[:m]
-                ranked.append((ids, sc))
-        obs_trace.add_span_active("rank", _t_rank,
-                                  time.perf_counter() - _t_rank)
+        with obs_trace.span("rank"):
+            mrs = [f[6] for f in fitted]
+            if all(m is not None for m in mrs):
+                masks = [(pos, neg, incl)
+                         for (_, _, _, pos, neg, incl, _, _) in fitted]
+                ranked, hb = self._rank_device(scores_dev, masks, max(mrs),
+                                               bound, view)
+                agg["host_bytes_transferred"] += hb
+                ranked = [(ids[:m], sc[:m])
+                          for (ids, sc), m in zip(ranked, mrs)]
+            else:
+                # any full-result request forces the score buffer to the
+                # host ONCE; ranking shares the oracle so truncated
+                # requests still see the exact device-ranking prefix
+                counts = np.ascontiguousarray(
+                    self._scores_to_host(scores_dev, view).T)
+                # sparse buffers cross as tiles: price what actually moved
+                agg["host_bytes_transferred"] += (
+                    scores_dev.nbytes
+                    if isinstance(scores_dev, SparseScores)
+                    else int(counts.nbytes))
+                ranked = []
+                for q, (_, _, _, pos, neg, incl, m, _) in enumerate(fitted):
+                    ids, sc = self._rank(counts[q], pos, neg, incl)
+                    if m is not None:
+                        ids, sc = ids[:m], sc[:m]
+                    ranked.append((ids, sc))
         t_query = time.perf_counter() - t0
 
         # ---- de-mux to per-request results -----------------------------

@@ -671,7 +671,7 @@ def _flat_query_acc_fn(capacity: int, use_pallas: bool):
     ``capacity`` is GLOBAL here (the engine sizes it like the
     single-device path)."""
 
-    def fn(rows4, zlo3, zhi3, inv_virt, scores, lo, hi, oh):
+    def score_flat_dense(rows4, zlo3, zhi3, inv_virt, scores, lo, hi, oh):
         s, nbm, block, d = rows4.shape
         nlm, q = scores.shape[1], scores.shape[2]
         counts, cand, n_hit = kops.fused_query(
@@ -688,7 +688,7 @@ def _flat_query_acc_fn(capacity: int, use_pallas: bool):
         ok = n_hit <= capacity
         return jnp.where(ok, acc, flat).reshape(scores.shape), st3
 
-    return jax.jit(fn)
+    return jax.jit(score_flat_dense)
 
 
 @functools.lru_cache(maxsize=128)
@@ -707,7 +707,7 @@ def _sharded_query_acc_fn(mesh, capacity: int, use_pallas: bool, nb: int):
 
     inner = _shard_call(local, mesh, 5, 3)
 
-    def fn(rows4, zlo3, zhi3, inv2, scores, lo, hi, oh):
+    def score_sharded_dense(rows4, zlo3, zhi3, inv2, scores, lo, hi, oh):
         acc, n_hit = inner(rows4, zlo3, zhi3, inv2, scores, lo, hi, oh)
         # reduce the [S] survivor counts to THREE ints inside the program
         # (max -> retry capacity, sum-refined + sum -> stats): the one
@@ -723,7 +723,7 @@ def _sharded_query_acc_fn(mesh, capacity: int, use_pallas: bool, nb: int):
         ok = st3[0] <= capacity
         return jnp.where(ok, acc, scores), st3
 
-    return jax.jit(fn)
+    return jax.jit(score_sharded_dense)
 
 
 def sharded_query_accumulate(sindex: ShardedZoneMapIndex,
@@ -778,7 +778,7 @@ def _sparse_probe_fn(capacity: int, use_pallas: bool):
     Returns (counts [C, block, Q], gids [C, block], ok [C, block],
              st [2] int32 = (n_hit, n_match))."""
 
-    def fn(rows3, zlo, zhi, gids_b, lo, hi, oh):
+    def score_sparse_probe(rows3, zlo, zhi, gids_b, lo, hi, oh):
         counts, cand, n_hit = kops.fused_query(
             rows3, zlo, zhi, lo, hi, oh, capacity=capacity,
             use_pallas=use_pallas)
@@ -786,7 +786,7 @@ def _sparse_probe_fn(capacity: int, use_pallas: bool):
         st = jnp.stack([n_hit, ok.sum().astype(jnp.int32)])
         return counts, gids, ok, st
 
-    return jax.jit(fn)
+    return jax.jit(score_sparse_probe)
 
 
 @functools.lru_cache(maxsize=128)
@@ -797,7 +797,7 @@ def _flat_sparse_probe_fn(capacity: int, use_pallas: bool):
     (counts [C, block, Q], gids/ok [C, block]) and the same [5] stat
     contract as the mesh probe — global figures in the per-shard slots."""
 
-    def fn(rows4, zlo3, zhi3, gids3, lo, hi, oh):
+    def score_flat_sparse_probe(rows4, zlo3, zhi3, gids3, lo, hi, oh):
         s, nbm, block, d = rows4.shape
         counts, cand, n_hit = kops.fused_query(
             rows4.reshape(s * nbm, block, d),
@@ -810,7 +810,7 @@ def _flat_sparse_probe_fn(capacity: int, use_pallas: bool):
                         nm, nm])
         return counts, gids, ok, st
 
-    return jax.jit(fn)
+    return jax.jit(score_flat_sparse_probe)
 
 
 @functools.lru_cache(maxsize=128)
@@ -832,14 +832,14 @@ def _sharded_sparse_probe_fn(mesh, capacity: int, use_pallas: bool):
 
     inner = _shard_call(local, mesh, 4, 3)
 
-    def fn(rows4, zlo3, zhi3, gids3, lo, hi, oh):
+    def score_sharded_sparse_probe(rows4, zlo3, zhi3, gids3, lo, hi, oh):
         counts, gids, ok, n_hit, nm = inner(rows4, zlo3, zhi3, gids3,
                                             lo, hi, oh)
         st = jnp.stack([n_hit.max(), jnp.minimum(n_hit, capacity).sum(),
                         n_hit.sum(), nm.max(), nm.sum()])
         return counts, gids, ok, st
 
-    return jax.jit(fn)
+    return jax.jit(score_sharded_sparse_probe)
 
 
 @functools.lru_cache(maxsize=128)

@@ -238,7 +238,8 @@ def _seg_query_acc_fn(capacity: int, use_pallas: bool):
     ``capacity`` bounds the gather GLOBALLY across all segments — one
     budget for the whole virtual space, no per-segment rounding waste."""
 
-    def fn(rows3, zlo, zhi, inv_virt, valid, scores, lo, hi, oh, seg_boff):
+    def score_segmented_dense(rows3, zlo, zhi, inv_virt, valid, scores,
+                              lo, hi, oh, seg_boff):
         nb = rows3.shape[0]
         counts, cand, n_hit = kops.fused_query(
             rows3, zlo, zhi, lo, hi, oh, capacity=capacity,
@@ -259,7 +260,7 @@ def _seg_query_acc_fn(capacity: int, use_pallas: bool):
         out = jnp.where(n_hit <= capacity, acc, scores)
         return out, jnp.concatenate([n_hit[None], per_seg])
 
-    return jax.jit(fn)
+    return jax.jit(score_segmented_dense)
 
 
 def segmented_query_accumulate(segx: SegmentedZoneMapIndex,
@@ -293,7 +294,8 @@ def _seg_sparse_probe_fn(capacity: int, use_pallas: bool):
     Returns (counts [C, block, Q], gids/ok [C, block],
              st [2 + S] int32 = (n_hit, n_match, per-segment refined))."""
 
-    def fn(rows3, zlo, zhi, gids_v, valid, lo, hi, oh, seg_boff):
+    def score_segmented_sparse_probe(rows3, zlo, zhi, gids_v, valid, lo,
+                                     hi, oh, seg_boff):
         counts, cand, n_hit = kops.fused_query(
             rows3, zlo, zhi, lo, hi, oh, capacity=capacity,
             use_pallas=use_pallas)
@@ -306,7 +308,7 @@ def _seg_sparse_probe_fn(capacity: int, use_pallas: bool):
                               ok.sum().astype(jnp.int32)[None], per_seg])
         return counts, gids, ok, st
 
-    return jax.jit(fn)
+    return jax.jit(score_segmented_sparse_probe)
 
 
 def segmented_sparse_probe(segx: SegmentedZoneMapIndex, blo: jax.Array,

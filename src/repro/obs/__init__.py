@@ -12,10 +12,10 @@ One subsystem, three surfaces:
 
 ``Observability`` bundles them per server: the ``QueryServer`` owns one
 and folds every finished trace's spans into ``span_seconds{name=}``
-histograms, which is where ``serve_load``'s ``stage_frac_*`` cells come
-from. Layering contract: this package imports nothing from ``repro``
+histograms, which ``/metrics`` exposes. Layering contract: this package imports nothing from ``repro``
 (stdlib only), so core, persist, and serve can all depend on it while
-core stays importable without the serving stack."""
+core stays importable without the serving stack. The layers that
+import JAX install the profiler hooks (``trace.install_hooks``)."""
 from __future__ import annotations
 
 import threading
@@ -41,11 +41,6 @@ __all__ = [
     "round_scope", "round_mark", "new_trace_id",
     "profile_site", "bind_registry", "Observability",
 ]
-
-# the stage names serve_load attributes wall time to; "other" absorbs
-# the remainder so fractions always sum to ~1
-STAGE_SPANS = ("fit", "device_round", "rank")
-
 
 class Observability:
     """Per-server bundle: registry + trace store + enable switches.
@@ -82,16 +77,23 @@ class Observability:
     def enabled(self) -> bool:
         return self.metrics_enabled or self.tracing_enabled
 
-    def new_trace(self, trace_id: Optional[str] = None) -> Optional[Trace]:
-        """A fresh trace when tracing is on; None (caller skips all
-        trace work) otherwise."""
+    def new_trace(self, trace_id: Optional[str] = None, *,
+                  t0: Optional[float] = None,
+                  held: bool = False) -> Optional[Trace]:
+        """A fresh trace with its ``request`` root open from ``t0``
+        (default now) when tracing is on; None (caller skips all trace
+        work) otherwise. ``held``: the caller (the HTTP front end)
+        finishes it, not the server."""
         if not self.tracing_enabled:
             return None
-        return Trace(trace_id)
+        return Trace(trace_id, t0=t0, held=held)
 
     def observe_trace(self, trace: Trace, status: str = "ok") -> None:
-        """Finish + archive a trace: status stamped, spans folded into
-        the per-stage histograms, ring/slow-log updated."""
+        """Finish + archive a trace, once: status stamped, root closed,
+        spans folded into the per-stage histograms, ring/slow-log
+        updated. A trace already finished is left as it is."""
+        if trace.finished_s is not None:
+            return
         trace.finish(status)
         if self.metrics_enabled:
             for sp in list(trace.spans):

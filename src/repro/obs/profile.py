@@ -24,8 +24,8 @@ from .metrics import Histogram, MetricsRegistry, default_registry
 __all__ = ["profile", "record", "bind_registry", "set_enabled",
            "enabled", "PROFILE_SITES"]
 
-# the sanctioned site names; new sites should be added here so the
-# serve_load stage attribution and DESIGN.md §17 stay in sync
+# the sanctioned site names; new sites should be added here so
+# DESIGN.md §17 stays in sync
 PROFILE_SITES = ("jit_dispatch", "device_sync", "wal_fsync", "compact")
 
 _tls = threading.local()

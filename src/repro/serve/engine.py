@@ -34,6 +34,7 @@ import logging
 import queue
 import threading
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -282,23 +283,48 @@ class QueryServer:
     def _close_queue_span(self, req) -> None:
         """End the queue span stamped at admission. It runs from the
         enqueue mark to HANDLE entry on the serving thread, so batch-
-        window formation wait is inside it (the trace's span sum must
+        window formation wait is inside it (the root's children must
         account for the full wall — a gap between pop and dispatch
         would be invisible time)."""
         tr = self._trace_of(req)
         if tr is not None:
-            tr.span_from_mark("queued", "queue")
+            tr.close("queue")
+
+    def _note_window_wait(self, reqs, t_open: float, t_close: float):
+        """Each request's share of the time the serving thread held its
+        window open after taking the first request: ``window_wait``, a
+        child of the request's still-open queue span, from the later of
+        its own queueing and the window's opening to the window's close.
+        The queue's time outside it is the wait behind other work."""
+        for r in reqs:
+            tr = self._trace_of(r)
+            q = tr.pending("queue") if tr is not None else None
+            if q is not None:
+                t0 = max(t_open, q.t0)
+                tr.add_span("window_wait", t0, max(t_close - t0, 0.0),
+                            None, None, q.span_id)
+
+    def _hand_off(self, out, req, resp) -> None:
+        """Answer a request; the put opens the ``handoff`` span the
+        front end closes when its event loop resumes with the answer."""
+        tr = self._trace_of(req)
+        if tr is not None:
+            tr.open("handoff", annotated=False)
+        out.put(resp)
 
     def _finish_trace(self, req, resp: QueryResponse) -> None:
-        """Stamp the outcome, fold spans into the per-stage histograms,
-        archive in the ring (+ slow-query log), and echo the trace id
-        on the response. Idempotent via Trace.finish."""
+        """Echo the trace id on the response and, for a trace the server
+        created, stamp the outcome, fold spans into the per-stage
+        histograms and archive it in the ring (+ slow-query log). A held
+        trace is finished by the front end that created it, after the
+        handoff. Idempotent via observe_trace."""
         tr = self._trace_of(req)
         if tr is None:
             return
         tr.attrs.setdefault("request_id", req.request_id)
-        status = "ok" if resp.ok else (resp.error_type or "error")
-        self.obs.observe_trace(tr, status)
+        if not tr.held:
+            self.obs.observe_trace(
+                tr, "ok" if resp.ok else (resp.error_type or "error"))
         resp.info.setdefault("trace_id", tr.trace_id)
 
     def _observe_latency(self, resp: QueryResponse) -> None:
@@ -633,18 +659,23 @@ class QueryServer:
             self._bump("batches")
             return [self.handle(reqs[0])]
         t0 = time.perf_counter()
-        for r in reqs:
-            self._close_queue_span(r)
         traces = [t for t in (self._trace_of(r) for r in reqs)
                   if t is not None]
-        window_dl = self._window_deadline(reqs)
-        kws = [self._query_kwargs(r) for r in reqs]
-        batch = [{"pos_ids": r.pos_ids, "neg_ids": r.neg_ids,
-                  "model": r.model, **kw} for r, kw in zip(reqs, kws)]
-        # cache keys computed BEFORE the device phase: a mutation landing
-        # mid-window moves the epoch and the store-time cross-check in
-        # ResultCache.put refuses the insert (never-stale)
-        keys = [self._cache_key(r, kw) for r, kw in zip(reqs, kws)]
+        # window assembly (kwargs, batch dicts, cache keys) is shared
+        # pre-device wall — billed like the fit span
+        with obs_trace.attach(traces), \
+                obs_trace.span("window", {"window": len(reqs)}):
+            for r in reqs:
+                self._close_queue_span(r)
+            window_dl = self._window_deadline(reqs)
+            kws = [self._query_kwargs(r) for r in reqs]
+            batch = [{"pos_ids": r.pos_ids, "neg_ids": r.neg_ids,
+                      "model": r.model, **kw} for r, kw in zip(reqs, kws)]
+            # cache keys computed BEFORE the device phase: a mutation
+            # landing mid-window moves the epoch and the store-time
+            # cross-check in ResultCache.put refuses the insert
+            # (never-stale)
+            keys = [self._cache_key(r, kw) for r, kw in zip(reqs, kws)]
 
         def run():
             return self.engine.query_batch(batch, deadline_s=window_dl)
@@ -653,11 +684,6 @@ class QueryServer:
             # device phase — OUTSIDE the retry wrapper, so each attempt
             # leaves its own fit/device-round spans on each trace
             with obs_trace.attach(traces):
-                # window assembly (kwargs, batch dicts, cache keys) is
-                # shared pre-device wall — billed like the fit span
-                obs_trace.add_span_active("window", t0,
-                                          time.perf_counter() - t0,
-                                          {"window": len(reqs)})
                 if self.retry_policy is not None:
                     outs = self.retry_policy.call(
                         run, deadline_s=window_dl,
@@ -757,7 +783,7 @@ class QueryServer:
         # request's admission + queue spans explain WHERE it died
         self._close_queue_span(req)
         self._finish_trace(req, resp)
-        out.put(resp)
+        self._hand_off(out, req, resp)
         return out
 
     def _request_cost(self, req) -> float:
@@ -782,7 +808,7 @@ class QueryServer:
         # trace born at ADMISSION (tracing enabled and none attached yet
         # — the HTTP layer creates its own to honor X-Request-Id)
         if isinstance(req, QueryRequest) and req.trace is None:
-            req.trace = self.obs.new_trace()
+            req.trace = self.obs.new_trace(t0=t_sub)
         out: "queue.Queue[QueryResponse]" = queue.Queue(maxsize=1)
         try:
             self._fault("submit")    # serve-layer chaos seam
@@ -813,11 +839,11 @@ class QueryServer:
         tr = self._trace_of(req)
         if tr is not None:
             # admission span: deadline stamp + rate limit + shed checks;
-            # the queue span opens here (mark) and closes at handle
-            # entry, so window-formation wait is INSIDE it
+            # the queue span opens here and closes at handle entry (on
+            # the serving thread), so window-formation wait is INSIDE it
             tr.add_span("admission", t_sub,
                         time.perf_counter() - t_sub)
-            tr.mark("queued")
+            tr.open("queue", annotated=False)
         admitted, evicted = self._q.offer((req, out),
                                           cost=self._request_cost(req))
         if not admitted:
@@ -900,20 +926,24 @@ class QueryServer:
                 continue
             self._update_health()
             batch = [first]
-            deadline = time.perf_counter() + self.batch_window_s
-            while len(batch) < self.max_batch:
-                item = self._pop_live(
-                    max(deadline - time.perf_counter(), 0))
-                if item is None:
-                    break
-                if isinstance(item[0], IngestRequest):
-                    self._held = item      # closes this window; runs next
-                    break
-                batch.append(item)
+            t_open = time.perf_counter()
+            deadline = t_open + self.batch_window_s
+            with obs_trace.annotate("window_wait") \
+                    if self.obs.tracing_enabled else nullcontext():
+                while len(batch) < self.max_batch:
+                    item = self._pop_live(
+                        max(deadline - time.perf_counter(), 0))
+                    if item is None:
+                        break
+                    if isinstance(item[0], IngestRequest):
+                        self._held = item  # closes this window; runs next
+                        break
+                    batch.append(item)
             reqs = [b[0] for b in batch]
+            self._note_window_wait(reqs, t_open, time.perf_counter())
             resps = self.handle_batch(reqs)
-            for (_, out), resp in zip(batch, resps):
-                out.put(resp)
+            for (req, out), resp in zip(batch, resps):
+                self._hand_off(out, req, resp)
 
     def close(self, drain: bool = True):
         """Shut down the threaded front end. ``drain=True`` (default)

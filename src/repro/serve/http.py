@@ -28,6 +28,14 @@ the request through admission, device rounds, and the slow-query log;
 responses echo it back as ``X-Request-Id`` and as ``trace_id`` in the
 JSON body. Without the header the server mints one.
 
+Trace root (DESIGN.md §17): a ``POST /query`` trace is born when its
+request line has been read; its ``request`` root and the wire's own
+children — ``http_read`` (headers, body, JSON parse, up to the
+``QueryRequest``), ``handoff`` (the serving thread's answer to this
+loop resuming) and ``http_encode`` (payload build, ``json.dumps``) —
+close before the response is written, so whoever has the answer finds
+the whole tree in the trace ring.
+
 Error contract: the typed taxonomy maps to HTTP statuses via
 ``repro.serve.policy.http_status_for`` — ``rate_limited`` -> 429,
 ``overloaded``/``shutdown`` -> 503 (with ``Retry-After``),
@@ -225,13 +233,13 @@ class HttpFrontEnd:
                 parsed = await self._read_request(reader)
                 if parsed is None:
                     break
-                method, path, headers, body = parsed
+                method, path, headers, body, trace = parsed
                 keep_alive = headers.get("connection", "").lower() \
                     != "close"
                 extra_headers: Optional[Dict[str, str]] = None
                 try:
                     res = await self._dispatch(method, path, headers,
-                                               body)
+                                               body, trace)
                     status, payload = res[0], res[1]
                     if len(res) > 2:
                         extra_headers = res[2]
@@ -244,9 +252,17 @@ class HttpFrontEnd:
                     status, payload = 500, {"ok": False, "error": f"{e}",
                                             "error_type": "internal"}
                 self._note(path, status)
-                await self._write_response(writer, status, payload,
-                                           keep_alive,
-                                           extra_headers=extra_headers)
+                data = self._encode_response(status, payload, keep_alive,
+                                             extra_headers=extra_headers)
+                if trace is not None:
+                    # the root closes BEFORE the write: a reader holding
+                    # the answer must find the whole tree
+                    trace.close("http_encode")
+                    self.server.obs.observe_trace(
+                        trace, "ok" if status == 200
+                        else payload.get("error_type", "error"))
+                writer.write(data)
+                await writer.drain()
                 if not keep_alive:
                     break
         except (_BadRequest, asyncio.IncompleteReadError,
@@ -260,7 +276,10 @@ class HttpFrontEnd:
                 pass
 
     async def _read_request(self, reader: asyncio.StreamReader):
-        """One request: (method, path, headers, body) or None on EOF."""
+        """One request: (method, path, headers, body, trace) or None on
+        EOF. A ``POST /query`` gets its trace here, its root open from
+        the request line and ``http_read`` open until ``_query`` has the
+        parsed request; None for other routes or with tracing off."""
         try:
             line = await reader.readline()
         except ValueError:
@@ -271,6 +290,27 @@ class HttpFrontEnd:
         if len(parts) != 3 or not parts[2].startswith("HTTP/"):
             raise _BadRequest("malformed request line")
         method, path = parts[0].upper(), parts[1]
+        trace = None
+        if method == "POST" and path.partition("?")[0] == "/query":
+            trace = self.server.obs.new_trace(held=True)
+            if trace is not None:
+                trace.open("http_read")
+        try:
+            headers, body = await self._read_rest(reader)
+        except BaseException:
+            if trace is not None:
+                self.server.obs.observe_trace(trace, "bad_request")
+            raise
+        if trace is not None:
+            # a caller-supplied X-Request-Id becomes the trace id end to
+            # end (length-capped: the id lands in logs and the ring)
+            rid = headers.get("x-request-id", "")[:128]
+            if rid:
+                trace.trace_id = rid
+        return method, path, headers, body, trace
+
+    async def _read_rest(self, reader: asyncio.StreamReader):
+        """The headers and body after a request line."""
         headers: Dict[str, str] = {}
         hdr_bytes = 0
         while True:
@@ -289,13 +329,12 @@ class HttpFrontEnd:
         if length > _MAX_BODY_BYTES:
             raise _BadRequest("body too large", status=413)
         body = await reader.readexactly(length) if length else b""
-        return method, path, headers, body
+        return headers, body
 
-    async def _write_response(self, writer: asyncio.StreamWriter,
-                              status: int, payload,
-                              keep_alive: bool, *,
-                              extra_headers: Optional[Dict[str, str]]
-                              = None) -> None:
+    @staticmethod
+    def _encode_response(status: int, payload, keep_alive: bool, *,
+                         extra_headers: Optional[Dict[str, str]] = None
+                         ) -> bytes:
         # dict payloads go out as JSON; str payloads (the /metrics
         # exposition) as text/plain with the Prometheus version tag
         if isinstance(payload, str):
@@ -313,8 +352,7 @@ class HttpFrontEnd:
             head.append("Retry-After: 1")     # back-pressure, not failure
         for k, v in (extra_headers or {}).items():
             head.append(f"{k}: {v}")
-        writer.write(("\r\n".join(head) + "\r\n\r\n").encode() + data)
-        await writer.drain()
+        return ("\r\n".join(head) + "\r\n\r\n").encode() + data
 
     def _note(self, path: str, status: int) -> None:
         with self._stats_lock:
@@ -331,7 +369,7 @@ class HttpFrontEnd:
     # routing
     # ------------------------------------------------------------------
     async def _dispatch(self, method: str, path: str,
-                        headers: Dict[str, str], body: bytes):
+                        headers: Dict[str, str], body: bytes, trace=None):
         """Route one request. Returns ``(status, payload)`` or
         ``(status, payload, extra_response_headers)``; a str payload is
         written as text/plain (the Prometheus exposition)."""
@@ -340,7 +378,7 @@ class HttpFrontEnd:
             if method != "POST":
                 return 405, {"ok": False, "error": "POST required",
                              "error_type": "method_not_allowed"}
-            return await self._query(self._parse_json(body), headers)
+            return await self._query(self._parse_json(body), trace)
         if path == "/ingest":
             if method != "POST":
                 return 405, {"ok": False, "error": "POST required",
@@ -400,7 +438,7 @@ class HttpFrontEnd:
             self._req_id += 1
             return self._req_id
 
-    async def _resolve(self, req) -> Tuple[int, Dict, object]:
+    async def _resolve(self, req, trace=None) -> Tuple[int, Dict, object]:
         """Submit to the QueryServer and await the response WITHOUT
         blocking the event loop (thread-pool hop around the blocking
         queue.get). Returns (status, base payload, QueryResponse)."""
@@ -411,6 +449,9 @@ class HttpFrontEnd:
                     {"ok": False, "error": str(e), "error_type": e.code},
                     None)
         resp = await asyncio.to_thread(out.get, True, _RESOLVE_TIMEOUT_S)
+        if trace is not None:
+            trace.close("handoff")
+            trace.open("http_encode")
         if resp.ok:
             return 200, {"ok": True}, resp
         return (http_status_for(resp.error_type),
@@ -420,7 +461,7 @@ class HttpFrontEnd:
     # ------------------------------------------------------------------
     # handlers
     # ------------------------------------------------------------------
-    async def _query(self, body: Dict, headers: Dict[str, str]):
+    async def _query(self, body: Dict, trace=None):
         _check_fields(body, _QUERY_FIELDS)
         pos = _require_int_list(body, "pos_ids")
         neg = _require_int_list(body, "neg_ids")
@@ -434,21 +475,13 @@ class HttpFrontEnd:
         deadline_s = None if timeout_ms is None \
             else deadline_after(timeout_ms / 1e3)
         t0 = time.perf_counter()
-        # the trace is born HERE (not in submit) so a caller-supplied
-        # X-Request-Id becomes the trace id end to end (length-capped:
-        # the id lands in logs and the trace ring verbatim)
-        rid = headers.get("x-request-id", "")[:128] or None
-        trace = self.server.obs.new_trace(rid)
+        if trace is not None:
+            trace.close("http_read")
         req = QueryRequest(self._next_id(), pos, neg, model,
                            kwargs=kwargs, deadline_s=deadline_s,
                            source=str(body.get("source", "default")),
                            trace=trace)
-        status, payload, resp = await self._resolve(req)
-        if resp is None and trace is not None:
-            # submit refused (ServerClosed) before the server could own
-            # the trace — finish it here so nothing dangles
-            self.server.obs.observe_trace(
-                trace, status=payload.get("error_type", "shutdown"))
+        status, payload, resp = await self._resolve(req, trace)
         payload["request_id"] = req.request_id
         payload["e2e_ms"] = round(1e3 * (time.perf_counter() - t0), 3)
         if trace is not None:
