@@ -60,7 +60,7 @@ from repro.core.index import (ShardedZoneMapIndex, ZoneMapIndex,
                               sharded_rank_merge, sharded_sparse_probe,
                               sharded_survivor_tiles, sparse_probe)
 from repro.core.segments import (SegmentedCatalog, SegmentedZoneMapIndex,
-                                 segmented_fused_stats,
+                                 mask_tombstones, segmented_fused_stats,
                                  segmented_query_accumulate,
                                  segmented_sparse_probe)
 from repro.core.subsets import make_subsets
@@ -928,7 +928,7 @@ class SearchEngine:
         return {"blocks_touched": 0, "blocks_gathered": 0, "blocks_total": 0,
                 "bytes_touched": 0, "n_boxes": 0, "n_range_queries": 0,
                 "host_bytes_transferred": 0, "n_host_syncs": 0,
-                "retried_subsets": 0}
+                "retried_subsets": 0, "accumulate_rows": 0}
 
     @staticmethod
     def _accumulate_agg(agg: Dict, st: Dict, n_boxes: int) -> None:
@@ -986,26 +986,33 @@ class SearchEngine:
         with obs_trace.round_scope() as scope:
             scores, agg = self._device_scores_impl(jobs, nq, view,
                                                    deadline_s=deadline_s)
+            # rows scattered over n rows a subset: the dense accumulate's
+            # work against one pass over the whole buffer per subset
+            agg["accumulate_share"] = agg["accumulate_rows"] / max(
+                view.n * len(jobs), 1)
             # the window's own counters, on the last round: the slow-
             # query log says why a request was slow
             scope.set(n_host_syncs=agg["n_host_syncs"],
                       retried_subsets=agg["retried_subsets"],
-                      blocks_touched=agg["blocks_touched"])
+                      blocks_touched=agg["blocks_touched"],
+                      accumulate_rows=agg["accumulate_rows"],
+                      accumulate_share=agg["accumulate_share"])
             return scores, agg
 
     def _device_scores_impl(self, jobs, nq: int, view: _EngineView,
                             deadline_s=None):
         """Answer every subset's boxes and accumulate all counts into ONE
         persistent [n, nq] device score buffer in ORIGINAL row order
-        (row-major so each block's scatter update is contiguous).
+        (row-major so each row's [Q] update is contiguous).
 
         Per round: launch every pending subset's fused query (async
         dispatch, no blocking), then ONE batched device->host sync reads
         all survivor counts together. Subsets whose survivors exceeded
         capacity are re-queued with capacity >= the observed count and are
         the ONLY work the next round re-runs; everything else scatter-adds
-        into the score buffer on device (kops.accumulate_scores). The
-        common case is exactly one sync of a few int32s per query batch —
+        its C * block gathered rows into the score buffer by row id, on
+        device (kops.accumulate_scores; ``accumulate_rows`` counts them).
+        The common case is exactly one sync of a few int32s per query batch —
         the per-subset blocking int(n_hit) round-trips of the old path
         are gone.
 
@@ -1050,7 +1057,7 @@ class SearchEngine:
             # ONE batched sync covers the whole round's overflow checks
             n_hits = rnd.sync([l[6] for l in launched], agg)
             pending = []
-            for (sid, merged, owner, cap, counts, cand, _), nh in zip(
+            for (sid, merged, owner, cap, counts, cand, n_hit), nh in zip(
                     launched, n_hits):
                 index = view.indexes[sid]
                 nh = int(nh)
@@ -1070,8 +1077,8 @@ class SearchEngine:
                                     min(self._pow2ceil(nh), index.n_blocks)))
                     continue
                 scores = kops.accumulate_scores(scores, counts, cand,
-                                                index.device_inv_perm(),
-                                                nb=index.n_blocks)
+                                                n_hit, index.device_gids())
+                agg["accumulate_rows"] += cap * index.block
                 self._accumulate_agg(
                     agg, fused_stats(index, nh, cap, merged.n_boxes),
                     merged.n_boxes)
@@ -1140,6 +1147,9 @@ class SearchEngine:
                     pending.append((sid, merged, owner, self._cap_bucket(
                         mx, self._cap_blocks(sindex))))
                     continue
+                agg["accumulate_rows"] += (
+                    cap if self._shard_flat else self.n_shards * cap
+                ) * sindex.block
                 self._accumulate_agg(
                     agg, sharded_fused_stats(sindex, mx, sum_min, cap,
                                              merged.n_boxes,
@@ -1155,7 +1165,7 @@ class SearchEngine:
         (DESIGN.md §12): the score buffer is [N_total, nq] with row index
         == global id (the concatenated virtual space needs no remap), one
         fused program per subset covers base + every delta, tombstoned
-        rows are masked to 0 inside the accumulate, and the batched
+        rows are masked to 0 once, on the finished buffer, and the batched
         deferred sync carries [1 + S] ints per subset — the survivor
         total for the overflow check plus the per-segment refined-block
         attribution the honest stats report."""
@@ -1183,8 +1193,7 @@ class SearchEngine:
                          ).astype(np.float32))
                     scores, stvec = segmented_query_accumulate(
                         segx, scores, jnp.asarray(lo), jnp.asarray(hi),
-                        onehot, view.valid, capacity=cap,
-                        use_pallas=self.use_pallas)
+                        onehot, capacity=cap, use_pallas=self.use_pallas)
                     launched.append((sid, merged, owner, cap, stvec))
             # ONE batched sync: [J, 1 + S] int32 for the whole round
             stvecs = rnd.sync([l[4] for l in launched], agg)
@@ -1203,6 +1212,7 @@ class SearchEngine:
                     pending.append((sid, merged, owner,
                                     min(self._pow2ceil(nh), segx.n_blocks)))
                     continue
+                agg["accumulate_rows"] += cap * segx.block
                 st_d = segmented_fused_stats(segx, nh, st[1:], cap,
                                              merged.n_boxes,
                                              view.live_rows)
@@ -1211,6 +1221,7 @@ class SearchEngine:
                 self._accumulate_agg(agg, st_d, merged.n_boxes)
             agg["retried_subsets"] += len(pending)
         agg["per_segment_blocks_touched"] = per_seg_agg.tolist()
+        scores = mask_tombstones(scores, view.valid)
         self._note_dense_buffer(agg, scores, nq, view)
         return scores, self._finalize_agg(agg, view)
 

@@ -80,9 +80,6 @@ class ZoneMapIndex:
     # lazily-populated device mirror: (rows3 [NB, block, d'], zlo, zhi)
     _dev: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = field(
         default=None, repr=False, compare=False)
-    # lazily-populated device inverse-permutation mirror [n_rows] int32
-    _dev_inv_perm: Optional[jax.Array] = field(
-        default=None, repr=False, compare=False)
     # lazily-populated global-row-id mirror [NB, block] int32 (-1 padding)
     _dev_gids: Optional[jax.Array] = field(
         default=None, repr=False, compare=False)
@@ -106,31 +103,15 @@ class ZoneMapIndex:
             self._dev = (rows3, jnp.asarray(self.zlo), jnp.asarray(self.zhi))
         return self._dev
 
-    def device_inv_perm(self) -> jax.Array:
-        """[n_rows] int32 inverse permutation (ORIGINAL row id -> Morton
-        position), uploaded ONCE and cached alongside the device mirror.
-        Device-resident score accumulation (kernels/ops.accumulate_scores)
-        gathers through it to convert Morton-order counts into original
-        row order without any host de-mux; padded Morton slots are never
-        gathered because only the n_rows real rows appear here."""
-        if self._dev_inv_perm is None:
-            self._dev_inv_perm = jnp.asarray(self.inv_perm())
-        return self._dev_inv_perm
-
-    def inv_perm(self) -> np.ndarray:
-        """Host copy of the inverse permutation device_inv_perm caches."""
-        valid = self.perm >= 0
-        inv = np.empty(self.n_rows, np.int32)
-        inv[self.perm[valid]] = np.nonzero(valid)[0].astype(np.int32)
-        return inv
-
     def device_gids(self) -> jax.Array:
         """[NB, block] int32 GLOBAL row id per (block, slot) — the
         permutation reshaped to the block grid, -1 on padding slots.
-        The survivor-sparse path labels fused tiles with it
-        (kernels/ops.tile_candidates); uploaded once and cached like the
-        other mirrors. For a monolithic index global id == original row
-        id; sharded/segmented wrappers add their own offsets."""
+        The dense accumulate scatters gathered counts by it
+        (kernels/ops.accumulate_scores) and the survivor-sparse path
+        labels fused tiles with it (kernels/ops.tile_candidates);
+        uploaded once and cached like the other mirrors. For a
+        monolithic index global id == original row id;
+        sharded/segmented wrappers add their own offsets."""
         if self._dev_gids is None:
             self._dev_gids = jnp.asarray(
                 np.ascontiguousarray(self.perm.astype(np.int32).reshape(
@@ -186,14 +167,11 @@ class ZoneMapIndex:
         """Actual RESIDENT device-mirror bytes by kind (0 for mirrors not
         yet uploaded) — what index_stats aggregates so the memory claims
         are measurable rather than inferred."""
-        out = {"rows": 0, "zones": 0, "inv_perm": 0, "gids": 0,
-               "quantized": 0}
+        out = {"rows": 0, "zones": 0, "gids": 0, "quantized": 0}
         if self._dev is not None:
             rows3, zlo, zhi = self._dev
             out["rows"] = int(rows3.nbytes)
             out["zones"] = int(zlo.nbytes) + int(zhi.nbytes)
-        if self._dev_inv_perm is not None:
-            out["inv_perm"] = int(self._dev_inv_perm.nbytes)
         if self._dev_gids is not None:
             out["gids"] = int(self._dev_gids.nbytes)
         if self._dev_quant is not None:
@@ -438,14 +416,16 @@ class ShardedZoneMapIndex:
     ZoneMapIndex over them (Morton order is shard-local; a row's global
     id is its shard offset + local id, so ids never need a lookup table).
     The device mirror stacks every shard to the SAME padded geometry —
-    [S, NBmax, block, d'] rows, [S, NBmax, d'] zones, [S, Nloc_max]
-    inverse permutations — so one program (vmapped on a single device,
+    [S, NBmax, block, d'] rows, [S, NBmax, d'] zones, [S, NBmax, block]
+    row-id grids — so one program (vmapped on a single device,
     shard_map'd across a mesh) serves every shard: padded zones are empty
     intervals that survive no prune, padded rows are +inf and inside no
-    box, and padded inverse-permutation slots point at ``NBmax * block``,
-    which accumulate_scores' extended slot table resolves to a zero
-    gather. Query results are therefore bitwise-independent of the shard
-    count (tests/test_sharded_query.py pins it)."""
+    box, and padded grid slots hold -1, which accumulate_scores drops.
+    The ceil-split makes every shard but a ragged tail exactly Nloc_max
+    rows long, so a global id is also the row of the flattened [S *
+    Nloc_max] score buffer. Query results are therefore
+    bitwise-independent of the shard count (tests/test_sharded_query.py
+    pins it)."""
     dims: np.ndarray
     shards: List[ZoneMapIndex]    # per-shard local indexes
     offsets: np.ndarray           # [S + 1] global row offsets
@@ -453,8 +433,6 @@ class ShardedZoneMapIndex:
     n_rows: int
     subset_id: int = -1
     _dev: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = field(
-        default=None, repr=False, compare=False)
-    _dev_inv_perm: Optional[jax.Array] = field(
         default=None, repr=False, compare=False)
     _dev_gids: Optional[jax.Array] = field(
         default=None, repr=False, compare=False)
@@ -524,34 +502,8 @@ class ShardedZoneMapIndex:
             self._dev = (self._put(rows4, mesh), self._put(zlo3, mesh),
                          self._put(zhi3, mesh))
             self._dev_mesh = mesh
-            self._dev_inv_perm = None      # re-commit alongside
-            self._dev_gids = None
+            self._dev_gids = None          # re-commit alongside
         return self._dev
-
-    def device_inv_perm(self, mesh=None) -> jax.Array:
-        """[S, Nloc_max] int32 shard-local inverse permutations, padded
-        with ``NBmax * block`` — the sentinel accumulate_scores' extended
-        slot table maps to a zero gather, so a ragged shard's padding
-        rows always score 0 and can never rank.
-
-        With ``mesh=None`` the VIRTUAL formulation comes back instead:
-        each shard's Morton positions offset by its block range in the
-        flattened [S * NBmax] block space (padding -> the global
-        sentinel), so the whole shard set can run as ONE fused index on
-        a single device (the fallback's flat fast path)."""
-        if self._dev_inv_perm is None or self._dev_mesh is not mesh:
-            s, nbm = self.n_shards, self.nb_max
-            pad = (s if mesh is None else 1) * nbm * self.block
-            inv = np.full((s, self.n_loc_max), pad, np.int32)
-            for i, sh in enumerate(self.shards):
-                if sh.n_rows:
-                    base = i * nbm * self.block if mesh is None else 0
-                    # host-side: a shard's own device mirror would
-                    # land on the default device, not the shard's
-                    inv[i, :sh.n_rows] = sh.inv_perm() + base
-            self.device_arrays(mesh)       # keep one mesh for the mirror
-            self._dev_inv_perm = self._put(inv, mesh)
-        return self._dev_inv_perm
 
     def device_gids(self, mesh=None) -> jax.Array:
         """[S, NBmax, block] int32 GLOBAL row ids per (shard, block,
@@ -576,14 +528,11 @@ class ShardedZoneMapIndex:
     def device_bytes(self) -> dict:
         """Resident device-mirror bytes by kind for the STACKED mirrors
         (the per-shard host indexes never upload their own)."""
-        out = {"rows": 0, "zones": 0, "inv_perm": 0, "gids": 0,
-               "quantized": 0}
+        out = {"rows": 0, "zones": 0, "gids": 0, "quantized": 0}
         if self._dev is not None:
             rows4, zlo3, zhi3 = self._dev
             out["rows"] = int(rows4.nbytes)
             out["zones"] = int(zlo3.nbytes) + int(zhi3.nbytes)
-        if self._dev_inv_perm is not None:
-            out["inv_perm"] = int(self._dev_inv_perm.nbytes)
         if self._dev_gids is not None:
             out["gids"] = int(self._dev_gids.nbytes)
         return out
@@ -641,7 +590,8 @@ def _shard_call(local, mesh, n_sharded: int, n_repl: int):
     arrays either way; scalars come back as [S]. The first ``n_sharded``
     arguments are stacked/sharded, the rest replicated."""
     if mesh is None:
-        return jax.vmap(local, in_axes=(0,) * n_sharded + (None,) * n_repl)
+        return jax.vmap(local, in_axes=(0,) * n_sharded + (None,) * n_repl,
+                        axis_name="shards")
 
     from jax.sharding import PartitionSpec as P
 
@@ -663,65 +613,69 @@ def _shard_call(local, mesh, n_sharded: int, n_repl: int):
 def _flat_query_acc_fn(capacity: int, use_pallas: bool):
     """Single-device fallback scoring: the stacked shard mirrors run as
     ONE fused index over the [S * NBmax] virtual block space (padding
-    blocks have empty zones and survive no prune), with the virtual
-    inverse permutation folding counts straight into the [S, Nloc_max,
-    Q] buffer's flat view. One device doing all shards' work pays the
-    SINGLE-index cost — one global capacity, no per-shard rounding waste
-    — while returning the same bits as the mesh formulation.
-    ``capacity`` is GLOBAL here (the engine sizes it like the
-    single-device path)."""
+    blocks have empty zones and survive no prune), with the counts
+    scattered by global id straight into the [S, Nloc_max, Q] buffer's
+    flat view (a global id IS its flat row under the ceil-split). One
+    device doing all shards' work pays the SINGLE-index cost — one
+    global capacity, no per-shard rounding waste — while returning the
+    same bits as the mesh formulation. ``capacity`` is GLOBAL here (the
+    engine sizes it like the single-device path)."""
 
-    def score_flat_dense(rows4, zlo3, zhi3, inv_virt, scores, lo, hi, oh):
+    def score_flat_dense(rows4, zlo3, zhi3, gids3, scores, lo, hi, oh):
         s, nbm, block, d = rows4.shape
         nlm, q = scores.shape[1], scores.shape[2]
         counts, cand, n_hit = kops.fused_query(
             rows4.reshape(s * nbm, block, d),
             zlo3.reshape(s * nbm, d), zhi3.reshape(s * nbm, d),
             lo, hi, oh, capacity=capacity, use_pallas=use_pallas)
-        flat = scores.reshape(s * nlm, q)
-        acc = kops.accumulate_scores(flat, counts, cand,
-                                     inv_virt.reshape(s * nlm),
-                                     nb=s * nbm)
+        # an overflowed attempt adds nothing; the caller retries it
+        n_live = jnp.where(n_hit <= capacity, n_hit, 0)
+        acc = kops.accumulate_scores(scores.reshape(s * nlm, q), counts,
+                                     cand, n_live,
+                                     gids3.reshape(s * nbm, block))
         # same [3]-int stat contract as the mesh path, with the GLOBAL
         # survivor count in every slot (there is no per-shard max here)
         st3 = jnp.stack([n_hit, jnp.minimum(n_hit, capacity), n_hit])
-        ok = n_hit <= capacity
-        return jnp.where(ok, acc, flat).reshape(scores.shape), st3
+        return acc.reshape(scores.shape), st3
 
     return jax.jit(score_flat_dense)
 
 
 @functools.lru_cache(maxsize=128)
-def _sharded_query_acc_fn(mesh, capacity: int, use_pallas: bool, nb: int):
+def _sharded_query_acc_fn(mesh, capacity: int, use_pallas: bool):
     """jit'd (and cached — eager shard_map re-traces per CALL, which is
     exactly the dispatch overhead the fused path exists to avoid) fused
     per-shard query + survivor-stat reduction + CONDITIONAL score
     accumulation, all as ONE device program per subset."""
 
-    def local(rows3, zlo, zhi, inv, sc, lo, hi, oh):
+    def local(rows3, zlo, zhi, gids, sc, lo, hi, oh):
         counts, cand, n_hit = kops.fused_query(
             rows3, zlo, zhi, lo, hi, oh, capacity=capacity,
             use_pallas=use_pallas)
-        acc = kops.accumulate_scores(sc, counts, cand, inv, nb=nb)
+        # keep the accumulation ONLY if no shard overflowed: an overflow
+        # dropped survivors, so the whole subset re-runs at a bigger
+        # capacity next round (speculating the common no-overflow case
+        # saves a second dispatch per subset; an overflowed attempt's
+        # increment is dropped, not added)
+        ok = jax.lax.pmax(n_hit, "shards") <= capacity
+        # shard-local rows: global id minus the shard's offset, which
+        # the ceil-split puts at shard * Nloc_max; padding stays < 0
+        base = jax.lax.axis_index("shards") * sc.shape[0]
+        acc = kops.accumulate_scores(sc, counts, cand,
+                                     jnp.where(ok, n_hit, 0), gids - base)
         return acc, n_hit
 
     inner = _shard_call(local, mesh, 5, 3)
 
-    def score_sharded_dense(rows4, zlo3, zhi3, inv2, scores, lo, hi, oh):
-        acc, n_hit = inner(rows4, zlo3, zhi3, inv2, scores, lo, hi, oh)
+    def score_sharded_dense(rows4, zlo3, zhi3, gids3, scores, lo, hi, oh):
+        acc, n_hit = inner(rows4, zlo3, zhi3, gids3, scores, lo, hi, oh)
         # reduce the [S] survivor counts to THREE ints inside the program
         # (max -> retry capacity, sum-refined + sum -> stats): the one
         # batched host sync stays flat in shard count
         st3 = jnp.stack([n_hit.max(),
                          jnp.minimum(n_hit, capacity).sum(),
                          n_hit.sum()])
-        # keep the accumulation ONLY if no shard overflowed: an overflow
-        # dropped survivors, so the whole subset re-runs at a bigger
-        # capacity next round (speculating the common no-overflow case
-        # saves a second dispatch per subset; the wasted adds on the
-        # rare overflow cost less than that dispatch did)
-        ok = st3[0] <= capacity
-        return jnp.where(ok, acc, scores), st3
+        return acc, st3
 
     return jax.jit(score_sharded_dense)
 
@@ -734,11 +688,11 @@ def sharded_query_accumulate(sindex: ShardedZoneMapIndex,
     """One subset's boxes against every shard, ONE device program: each
     shard runs the SAME fused zone-prune -> bounded gather -> segmented
     box-scan (kernels/ops.fused_query) over its slice of the stacked
-    device mirror and folds its counts into its [Nloc_max, Q] slice of
-    the score buffer (kernels/ops.accumulate_scores; the extended slot
-    table keeps ragged-shard padding at 0). ``capacity`` bounds the
-    gather PER SHARD; if ANY shard overflows the accumulation is
-    discarded on device and the caller retries the subset.
+    device mirror and scatters its counts by row id into its [Nloc_max,
+    Q] slice of the score buffer (kernels/ops.accumulate_scores; grid
+    padding is -1 and drops). ``capacity`` bounds the gather PER SHARD;
+    if ANY shard overflows the accumulation is discarded on device and
+    the caller retries the subset.
 
     Returns (scores' [S, Nloc_max, Q],
              hit_stats [3] int32 device scalars =
@@ -754,9 +708,8 @@ def sharded_query_accumulate(sindex: ShardedZoneMapIndex,
     if mesh is None:
         fn = _flat_query_acc_fn(int(capacity), bool(use_pallas))
     else:
-        fn = _sharded_query_acc_fn(mesh, int(capacity), bool(use_pallas),
-                                   sindex.nb_max)
-    return fn(rows4, zlo3, zhi3, sindex.device_inv_perm(mesh), scores,
+        fn = _sharded_query_acc_fn(mesh, int(capacity), bool(use_pallas))
+    return fn(rows4, zlo3, zhi3, sindex.device_gids(mesh), scores,
               blo, bhi, onehot)
 
 

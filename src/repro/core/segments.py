@@ -10,9 +10,9 @@ compact lifecycle so the engine can serve a catalog that GROWS:
            stable forever: a segment starting at ``offset`` owns global
            rows [offset, offset + n_rows), exactly the shard id contract.
   delete   writes tombstones into a device-resident validity mask —
-           geometry is untouched, dead rows simply accumulate score 0
-           (kernels/ops.accumulate_scores masks them) so ranked top-k
-           never surfaces them.
+           geometry is untouched, dead rows simply end with score 0
+           (the dense buffer is masked once a query, mask_tombstones) so
+           ranked top-k never surfaces them.
   compact  merges every sealed segment into ONE re-sorted segment (one
            global Morton order again) off the serving thread and swaps
            it in atomically. Tombstoned rows stay physically present so
@@ -25,10 +25,11 @@ Queries run base + deltas as ONE fused device program by the same move
 the sharded fallback used (DESIGN.md §11): every segment's blocks are
 concatenated into a single RAGGED virtual block space ([NB_total, block,
 d'] — no per-segment NBmax padding, segments are wildly different
-sizes), the per-segment inverse permutations are offset into it, and the
-flat fused query + accumulate + rank_topk pipeline runs exactly as it
-does for a monolithic index. Scores land in a [N_total, Q] buffer whose
-row index IS the global id, so ranking and training-id exclusion need no
+sizes), the per-segment row-id grids are offset to global ids in the same
+order, and the flat fused query + accumulate + rank_topk pipeline runs
+exactly as it does for a monolithic index. Scores land in a [N_total, Q]
+buffer whose row index IS the global id, so the accumulate scatters
+straight by global id and ranking and training-id exclusion need no
 remap at all.
 
 Snapshot / epoch discipline: every mutation builds a NEW immutable
@@ -105,11 +106,10 @@ class Segment:
 class SegmentedZoneMapIndex:
     """One feature subset's view of every segment, concatenated into the
     flat virtual block space. Quacks like a ZoneMapIndex where the engine
-    needs it to (device_arrays / n_blocks / block / subset_id), but its
-    inverse permutation is VIRTUAL: global row g maps to its segment's
-    Morton position offset by the segment's block range, so one
-    accumulate_scores call folds every segment's counts into the
-    [N_total, Q] buffer in global id order. Pure geometry — validity
+    needs it to (device_arrays / n_blocks / block / subset_id), and its
+    row-id grid (device_gids) names each virtual (block, slot)'s GLOBAL
+    id, so one accumulate_scores call folds every segment's counts into
+    the [N_total, Q] buffer in global id order. Pure geometry — validity
     (tombstones) lives on the Snapshot, so delete epochs share these
     objects and their cached device mirrors."""
     dims: np.ndarray
@@ -118,8 +118,6 @@ class SegmentedZoneMapIndex:
     block: int
     subset_id: int = -1
     _dev: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = field(
-        default=None, repr=False, compare=False)
-    _inv_virt: Optional[jax.Array] = field(
         default=None, repr=False, compare=False)
     _seg_blocks_dev: Optional[jax.Array] = field(
         default=None, repr=False, compare=False)
@@ -165,19 +163,6 @@ class SegmentedZoneMapIndex:
                                   for i in range(3))
         return self._dev
 
-    def device_inv_virt(self) -> jax.Array:
-        """[N_total] int32: global row id -> virtual Morton position
-        (segment-local position + the segment's block offset * block).
-        Segment order == global id order, so this is one concatenation;
-        padded tail-block slots never appear (per-segment inverse
-        permutations cover real rows only)."""
-        if self._inv_virt is None:
-            parts = [s.device_inv_perm() + jnp.int32(b * self.block)
-                     for s, b in zip(self.segs, self.seg_blocks[:-1])]
-            self._inv_virt = (parts[0] if len(parts) == 1
-                              else jnp.concatenate(parts))
-        return self._inv_virt
-
     def device_seg_blocks(self) -> jax.Array:
         if self._seg_blocks_dev is None:
             self._seg_blocks_dev = jnp.asarray(self.seg_blocks, jnp.int32)
@@ -188,8 +173,9 @@ class SegmentedZoneMapIndex:
         slot), -1 on padding slots: each segment's local permutation grid
         offset by the segment's global row offset, concatenated in the
         virtual block order. Built from the per-segment cached mirrors
-        on device (an append re-offsets only the delta), it labels the
-        survivor-sparse tiles so ranking needs no virtual->global remap."""
+        on device (an append re-offsets only the delta). The dense
+        accumulate scatters by it and the survivor-sparse tiles are
+        labelled with it, so ranking needs no virtual->global remap."""
         if self._gids_virt is None:
             parts = []
             for s, o in zip(self.segs, self.offsets[:-1]):
@@ -204,8 +190,7 @@ class SegmentedZoneMapIndex:
         mirrors plus this view's own concatenated copies (counted only
         when they are distinct arrays — a single-segment view shares the
         segment's mirror)."""
-        out = {"rows": 0, "zones": 0, "inv_perm": 0, "gids": 0,
-               "quantized": 0}
+        out = {"rows": 0, "zones": 0, "gids": 0, "quantized": 0}
         for s in self.segs:
             for k, v in s.device_bytes().items():
                 out[k] += v
@@ -213,8 +198,6 @@ class SegmentedZoneMapIndex:
             rows3, zlo, zhi = self._dev
             out["rows"] += int(rows3.nbytes)
             out["zones"] += int(zlo.nbytes) + int(zhi.nbytes)
-        if self._inv_virt is not None:
-            out["inv_perm"] += int(self._inv_virt.nbytes)
         if self._gids_virt is not None:
             out["gids"] += int(self._gids_virt.nbytes)
         return out
@@ -227,25 +210,28 @@ class SegmentedZoneMapIndex:
 
 
 # ----------------------------------------------------------------------
-# fused query + masked accumulate over the virtual block space
+# fused query + accumulate over the virtual block space
 # ----------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=128)
 def _seg_query_acc_fn(capacity: int, use_pallas: bool):
-    """jit'd fused query over the concatenated segment blocks + masked
-    score accumulation + per-segment survivor attribution, one device
-    program per subset (the segmented sibling of _flat_query_acc_fn).
+    """jit'd fused query over the concatenated segment blocks + score
+    accumulation by global id + per-segment survivor attribution, one
+    device program per subset (the segmented sibling of
+    _flat_query_acc_fn).
     ``capacity`` bounds the gather GLOBALLY across all segments — one
     budget for the whole virtual space, no per-segment rounding waste."""
 
-    def score_segmented_dense(rows3, zlo, zhi, inv_virt, valid, scores,
-                              lo, hi, oh, seg_boff):
-        nb = rows3.shape[0]
+    def score_segmented_dense(rows3, zlo, zhi, gids_v, scores, lo, hi, oh,
+                              seg_boff):
         counts, cand, n_hit = kops.fused_query(
             rows3, zlo, zhi, lo, hi, oh, capacity=capacity,
             use_pallas=use_pallas)
-        acc = kops.accumulate_scores(scores, counts, cand, inv_virt,
-                                     nb=nb, valid=valid)
+        # speculate the no-overflow case exactly like the sharded path:
+        # an overflowed attempt adds nothing (no live slot), the caller
+        # retries the subset at >= n_hit
+        n_live = jnp.where(n_hit <= capacity, n_hit, 0)
+        acc = kops.accumulate_scores(scores, counts, cand, n_live, gids_v)
         # attribute each REFINED block to its segment: cand partitions
         # into segments by the boundary table, fill slots past the
         # refined count masked out — so the per-segment figures sum to
@@ -255,31 +241,38 @@ def _seg_query_acc_fn(capacity: int, use_pallas: bool):
         refined = jnp.arange(capacity) < jnp.minimum(n_hit, capacity)
         per_seg = jnp.zeros((seg_boff.shape[0] - 1,), jnp.int32).at[
             seg_of].add(refined.astype(jnp.int32))
-        # speculate the no-overflow case exactly like the sharded path:
-        # discard on device, caller retries the subset at >= n_hit
-        out = jnp.where(n_hit <= capacity, acc, scores)
-        return out, jnp.concatenate([n_hit[None], per_seg])
+        return acc, jnp.concatenate([n_hit[None], per_seg])
 
     return jax.jit(score_segmented_dense)
 
 
+@jax.jit
+def mask_tombstones(scores: jax.Array, valid: jax.Array) -> jax.Array:
+    """The finished [N_total, Q] dense buffer with every tombstoned row's
+    scores zeroed (valid: [N_total] int32, 1 live), once a query: an
+    elementwise pass, so a dead row can never rank (rank_topk treats
+    score <= 0 as invalid). Applying it once to the sum equals masking
+    every subset's increment, since a dead row's total is 0 either way."""
+    return scores * valid[:, None]
+
+
 def segmented_query_accumulate(segx: SegmentedZoneMapIndex,
                                scores: jax.Array, blo: jax.Array,
-                               bhi: jax.Array, onehot: jax.Array,
-                               valid: jax.Array, *, capacity: int,
-                               use_pallas: bool = True):
+                               bhi: jax.Array, onehot: jax.Array, *,
+                               capacity: int, use_pallas: bool = True):
     """One subset's boxes against EVERY segment as one fused device
     program: zone-prune + bounded gather + segmented box-scan over the
-    concatenated virtual block space, counts folded into the global
-    [N_total, Q] score buffer through the virtual inverse permutation
-    with tombstoned rows masked to 0 at accumulation time.
+    concatenated virtual block space, counts scattered into the global
+    [N_total, Q] score buffer by global id (the virtual row-id grid).
+    Tombstones are the caller's to mask, once, on the finished buffer
+    (mask_tombstones).
 
     Returns (scores', stvec [1 + S] int32 = (total survivors, refined
     blocks per segment)) — device values; callers batch the sync."""
     rows3, zlo, zhi = segx.device_arrays()
     fn = _seg_query_acc_fn(int(capacity), bool(use_pallas))
-    return fn(rows3, zlo, zhi, segx.device_inv_virt(), valid, scores,
-              blo, bhi, onehot, segx.device_seg_blocks())
+    return fn(rows3, zlo, zhi, segx.device_gids(), scores, blo, bhi,
+              onehot, segx.device_seg_blocks())
 
 
 @functools.lru_cache(maxsize=128)
@@ -287,8 +280,8 @@ def _seg_sparse_probe_fn(capacity: int, use_pallas: bool):
     """Survivor-sparse probe over the virtual block space (the sparse
     sibling of _seg_query_acc_fn): fused query + tile labelling with the
     tombstone mask applied PER TILE ROW (tile_candidates drops dead rows
-    instead of accumulate_scores zeroing them — same zeros, applied at
-    the survivor granularity), plus the per-segment refined-block
+    where the dense path masks its finished buffer — same zeros, applied
+    at the survivor granularity), plus the per-segment refined-block
     attribution the honest-accounting stats are pinned on.
 
     Returns (counts [C, block, Q], gids/ok [C, block],

@@ -178,46 +178,36 @@ def batch_box_membership(x: jax.Array, lo: jax.Array, hi: jax.Array,
     return (jnp.all(inside, -1) & valid[:, None, :]).sum(-1).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("nb",))
+@jax.jit
 def accumulate_scores(scores: jax.Array, counts: jax.Array, cand: jax.Array,
-                      inv_perm: jax.Array, valid: jax.Array | None = None,
-                      *, nb: int) -> jax.Array:
+                      n_live: jax.Array, grid: jax.Array) -> jax.Array:
     """Add one subset's fused counts into the persistent per-query score
-    buffer, ON DEVICE and in ORIGINAL row order.
+    buffer, ON DEVICE, by row id.
 
     scores: [N, Q] int32 running scores; counts: [C, block, Q] from
-    fused_query (overflow slots already zeroed); cand: [C] gathered block
-    ids; inv_perm: [N] int32 original-row -> Morton-position map
-    (ZoneMapIndex.device_inv_perm); nb: the index's block count (static);
-    valid: optional [N] int32/bool row-liveness mask — a tombstoned row's
-    gathered count is zeroed HERE, at accumulation time, so a live
-    catalog's dead rows carry score 0 through every later stage and can
-    never rank (rank_topk treats score <= 0 as invalid). Masking the
-    increment rather than the final buffer keeps the contract local: any
-    mix of masked and unmasked subsets still sums to a masked total.
+    fused_query; cand: [C] gathered block ids; n_live: int32 scalar, the
+    leading slots of ``cand`` that carry counts (fused_query's n_hit; 0
+    discards the whole subset, which is how the fused score programs
+    drop an overflowed attempt without selecting between two buffers);
+    grid: [NB, block] int32 buffer row per (block, slot), -1 on padding
+    slots (the index's device_gids, or a shard's local view of it).
 
-    Formulated as a GATHER, not a scatter: a tiny [nb + 1] block->slot
-    table (C-element scatter — nonzero emits survivors in ascending block
-    order, so a genuine survivor's slot always beats the zero-count fill
-    slots that alias block 0 under min) lets every original row pull its
-    own count straight out of the compact fused result through the
-    inverse permutation — one dense vectorised pass, no row-granular
-    scatter. Blocks absent from ``cand`` resolve out of range and gather
-    0 (mode="fill"). The extra slot-table entry serves the sharded path:
-    inv_perm rows PADDED to ``nb * block`` land on slot nb (never a
-    survivor, cand < nb) and gather 0 too, so ragged shards stack into
-    one rectangular buffer without polluting real rows' scores. Nothing
-    here ever touches the host — this replaces the old [Q, n_rows] host
-    scatter."""
+    The work follows the gathered capacity, not the catalog: one
+    block-granular gather of C grid rows names the [C, block] target
+    rows, and one scatter-add of C * block rows lands them. Fill slots
+    past ``n_live`` (nonzero pads with block 0) and padding slots map
+    to distinct rows past the buffer and drop, so every target row is
+    unique within the subset. Nothing here touches the N rows by index:
+    the buffer update stays in place. Tombstones are not masked here —
+    the live catalog masks the finished buffer once a query. The scores
+    are int32 counts, so any order of adding them gives the same bits."""
     c, block, q = counts.shape
-    slot = jnp.full((nb + 1,), c, jnp.int32).at[cand].min(
-        jnp.arange(c, dtype=jnp.int32))
-    idx = slot[inv_perm // block] * block + inv_perm % block      # [N]
-    inc = jnp.take(counts.reshape(c * block, q), idx, axis=0,
-                   mode="fill", fill_value=0)
-    if valid is not None:
-        inc = inc * valid.astype(inc.dtype)[:, None]
-    return scores + inc
+    rows = jnp.take(grid, cand, axis=0)                      # [C, block]
+    live = (jnp.arange(c) < n_live)[:, None] & (rows >= 0)
+    past = scores.shape[0] + jnp.arange(c * block, dtype=jnp.int32)
+    rows = jnp.where(live, rows, past.reshape(c, block))
+    return scores.at[rows.reshape(c * block)].add(
+        counts.reshape(c * block, q), mode="drop", unique_indices=True)
 
 
 # ----------------------------------------------------------------------
@@ -248,7 +238,7 @@ def tile_candidates(counts: jax.Array, cand: jax.Array,
     row id per (block, slot) — -1 on padding slots (the device mirror
     built from the index permutation); valid: optional [n] row-liveness
     mask in GLOBAL id space (tombstoned rows are dropped here, the
-    sparse analogue of accumulate_scores' masked increment).
+    sparse analogue of the dense buffer's tombstone mask).
 
     Returns (gids [C, block] int32, ok [C, block] bool). ``ok`` is True
     only for real, live rows with a nonzero count in at least one query
@@ -428,8 +418,9 @@ def rank_topk(scores: jax.Array, train_ids: jax.Array, *, k: int,
 
     Rows with score <= 0 (incl. masked training rows) are invalid: their
     ids come back -1 and n_valid excludes them. Tombstoned rows of a live
-    catalog arrive here already zeroed (accumulate_scores' valid mask),
-    so they fall under the same rule — and because masking only LOWERS
+    catalog arrive here already zeroed (the engine masks the dense
+    buffer once a query; the sparse tiles drop dead rows), so they fall
+    under the same rule — and because masking only LOWERS
     scores, any ``score_bound`` that was valid for the unmasked buffer
     (the per-query box count) stays valid under tombstones, down to the
     all-dead edge where every query simply yields n_valid == 0.

@@ -8,8 +8,8 @@ Contracts pinned here:
     tie-breaks at the k-th score agree too), on both the device-ranked
     (max_results=k) and host-ranked (max_results=None) paths, including
     ragged tail segments and duplicate-row kth-score ties;
-  * tombstoned rows NEVER surface: masked at score accumulation
-    (kernels/ops.accumulate_scores' valid mask), dead in knn, dead on
+  * tombstoned rows NEVER surface: masked on the finished score buffer
+    (the dense buffer's once-a-query mask), dead in knn, dead on
     the scan path;
   * global ids are append-ordered and stable forever — refine() across
     an append keeps referring to the same rows;
@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from repro.core import knn as knn_mod
 from repro.core.engine import SearchEngine
-from repro.core.segments import SegmentedCatalog
+from repro.core.segments import SegmentedCatalog, mask_tombstones
 from repro.kernels import ops as kops
 from repro.serve.engine import IngestRequest, QueryRequest, QueryServer
 
@@ -351,20 +351,24 @@ def test_segment_stats_honest_accounting():
 
 
 def test_masked_accumulate_and_rank_under_tombstones():
-    """Kernel-level: accumulate_scores' valid mask zeroes exactly the
-    tombstoned rows' counts, and rank_topk with the query's score_bound
-    stays exact down to the all-dead edge (n_valid == 0)."""
+    """Kernel-level: the scatter-add by row id, then the once-a-query
+    tombstone mask (mask_tombstones), zeroes exactly the tombstoned
+    rows' scores, and rank_topk with the query's score_bound stays exact
+    down to the all-dead edge (n_valid == 0)."""
     rng = np.random.default_rng(0)
     n, block, nb, q = 256, 32, 8, 3
     counts = jnp.asarray(rng.integers(0, 5, (nb, block, q)), jnp.int32)
     cand = jnp.arange(nb, dtype=jnp.int32)
-    inv = jnp.asarray(rng.permutation(n), jnp.int32)
+    grid = rng.permutation(n).astype(np.int32).reshape(nb, block)
     valid = rng.integers(0, 2, n).astype(np.int32)
     base = np.asarray(kops.accumulate_scores(
-        jnp.zeros((n, q), jnp.int32), counts, cand, inv, nb=nb))
-    masked = np.asarray(kops.accumulate_scores(
-        jnp.zeros((n, q), jnp.int32), counts, cand, inv,
-        jnp.asarray(valid), nb=nb))
+        jnp.zeros((n, q), jnp.int32), counts, cand, jnp.int32(nb),
+        jnp.asarray(grid)))
+    want = np.zeros((n, q), np.int32)
+    want[grid.reshape(-1)] = np.asarray(counts).reshape(n, q)
+    np.testing.assert_array_equal(base, want)
+    masked = np.asarray(mask_tombstones(jnp.asarray(base),
+                                        jnp.asarray(valid)))
     np.testing.assert_array_equal(masked, base * valid[:, None])
     # ranking the masked buffer never surfaces a dead row, for every
     # rank method, with the true score bound

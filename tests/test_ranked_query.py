@@ -15,11 +15,14 @@ Contracts pinned here:
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.boxes import BoxSet
 from repro.core.engine import SearchEngine
-from repro.core.index import build_index, morton_code, query_index
+from repro.core.index import (build_index, build_sharded_index, morton_code,
+                              query_index)
+from repro.core.segments import SegmentedZoneMapIndex, mask_tombstones
 from repro.kernels import ops as kops
 
 
@@ -113,13 +116,138 @@ def test_accumulate_scores_matches_host_scatter():
         rows3, zlo, zhi, jnp.asarray(bs.lo), jnp.asarray(bs.hi), onehot,
         capacity=idx.n_blocks)
     scores = jnp.zeros((idx.n_rows, 1), jnp.int32)
-    scores = kops.accumulate_scores(scores, counts, cand,
-                                    idx.device_inv_perm(), nb=idx.n_blocks)
+    scores = kops.accumulate_scores(scores, counts, cand, n_hit,
+                                    idx.device_gids())
     # accumulation is additive: a second pass doubles every count
-    twice = kops.accumulate_scores(scores, counts, cand,
-                                   idx.device_inv_perm(), nb=idx.n_blocks)
+    twice = kops.accumulate_scores(scores, counts, cand, n_hit,
+                                   idx.device_gids())
     np.testing.assert_array_equal(np.asarray(scores)[:, 0], want)
     np.testing.assert_array_equal(np.asarray(twice)[:, 0], 2 * want)
+
+
+def _accumulate_case(case, rng):
+    """(buffer rows, [NB, block] grid of buffer rows with -1 padding,
+    live-row mask or None) for one kind of index."""
+    x = rng.normal(0, 1, (700, 3)).astype(np.float32)
+    dims = np.arange(3)
+    if case in ("static", "fill_slots", "full_capacity", "overflow"):
+        idx = build_index(x, dims, block=64)               # ragged tail
+        return idx.n_rows, np.asarray(idx.device_gids()), None
+    if case == "segmented":
+        # a base and two delta segments over global ids, tombstones on
+        segs = [build_index(x[a:b], dims, block=64)
+                for a, b in ((0, 500), (500, 620), (620, 700))]
+        segx = SegmentedZoneMapIndex(dims, segs,
+                                     np.array([0, 500, 620, 700]), 64)
+        valid = (rng.random(700) > 0.2).astype(np.int32)
+        return 700, np.asarray(segx.device_gids()), valid
+    # flat sharded: 5 shards over 7 rows -> 2, 2, 2, 1 (ragged), 0 (empty)
+    sx = build_sharded_index(x[:7], dims, 5, block=2)
+    assert list(sx.shard_rows) == [2, 2, 2, 1, 0]
+    nlm = sx.n_loc_max
+    # the ceil-split puts every non-empty shard's first global id at
+    # shard * Nloc_max: a global id IS its row of the flat buffer
+    occupied = sx.shard_rows > 0
+    np.testing.assert_array_equal(sx.offsets[:-1][occupied],
+                                  (np.arange(5) * nlm)[occupied])
+    g = np.asarray(sx.device_gids())
+    return 5 * nlm, g.reshape(-1, g.shape[-1]), None
+
+
+ACCUMULATE_CASES = ["static", "segmented", "flat_sharded", "fill_slots",
+                    "full_capacity", "overflow"]
+
+
+@pytest.mark.parametrize("case", ACCUMULATE_CASES)
+def test_accumulate_scores_matches_add_at_oracle(case):
+    """The survivor-sized scatter-add equals np.add.at by row id, over
+    two subsets into one buffer: fill slots past n_live (block 0 again,
+    as nonzero pads) and grid padding add nothing, n_live == 0 (an
+    overflowed attempt) adds nothing, and capacity == n_blocks works."""
+    rng = np.random.default_rng(ACCUMULATE_CASES.index(case))
+    n, grid, valid = _accumulate_case(case, rng)
+    nb, block = grid.shape
+    q = 2
+    want = np.zeros((n, q), np.int64)
+    scores = jnp.zeros((n, q), jnp.int32)
+    for _ in range(2):
+        if case == "full_capacity":
+            c = n_live = nb
+        else:
+            c = max(nb // 2, 1)
+            n_live = c - 3 if case == "fill_slots" else c
+        hit = np.sort(rng.choice(nb, n_live, replace=False))
+        cand = np.concatenate([hit, np.zeros(c - n_live, np.int64)])
+        counts = rng.integers(0, 5, (c, block, q)).astype(np.int32)
+        if case == "overflow":
+            n_live = 0
+        scores = kops.accumulate_scores(
+            scores, jnp.asarray(counts), jnp.asarray(cand, jnp.int32),
+            jnp.int32(n_live), jnp.asarray(grid))
+        ids = grid[cand[:n_live]]                          # [n_live, block]
+        real = ids >= 0
+        np.add.at(want, ids[real], counts[:n_live][real])
+    got = np.asarray(scores)
+    if valid is not None:
+        got = np.asarray(mask_tombstones(scores, jnp.asarray(valid)))
+        want = want * valid[:, None]
+    np.testing.assert_array_equal(got, want)
+    if case == "overflow":
+        assert not got.any()
+
+
+def _index_ops(jaxpr):
+    """(primitive name, number of indices) of every gather and scatter,
+    sub-jaxprs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            shape = eqn.invars[1].aval.shape
+            out.append((name, int(np.prod(shape[:-1]))))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)          # ClosedJaxpr
+                if hasattr(sub, "eqns"):
+                    out.extend(_index_ops(sub))
+    return out
+
+
+def test_accumulate_cost_follows_capacity():
+    """No gather or scatter of the accumulate carries more than C * block
+    indices: at N = 65,536 rows and C = 8 blocks of 1024 the work is the
+    8 grid rows and the 8,192 gathered rows, not the catalog."""
+    n, block, c, q = 65_536, 1024, 8, 1
+    jaxpr = jax.make_jaxpr(kops.accumulate_scores)(
+        jnp.zeros((n, q), jnp.int32), jnp.zeros((c, block, q), jnp.int32),
+        jnp.zeros((c,), jnp.int32), jnp.int32(c),
+        jnp.zeros((n // block, block), jnp.int32))
+    ops = _index_ops(jaxpr.jaxpr)
+    assert ops and max(k for _, k in ops) <= c * block, ops
+    assert ("scatter-add", c * block) in ops, ops
+
+
+@pytest.mark.parametrize("kind", ["static", "live", "sharded"])
+def test_dense_engine_counts_accumulate_rows(catalog, kind):
+    """``accumulate_rows`` counts C * block rows per accepted subset —
+    the gathered blocks' rows, so blocks_gathered * block when nothing
+    retried — and ``accumulate_share`` puts them over n rows a subset."""
+    feats, labels = catalog
+    kw = {"live": kind == "live", "n_shards": 2 if kind == "sharded" else 1}
+    eng = SearchEngine(feats, n_subsets=6, subset_dim=6, block=64, seed=0,
+                       score_mode="dense", capacity_frac=1.0, **kw)
+    pos, neg = _query_sets(labels, 1)
+    st = eng.query(pos, neg, model="dbranch", max_results=20).stats
+    assert st["retried_subsets"] == 0
+    assert st["accumulate_rows"] == st["blocks_gathered"] * 64 > 0
+    # every subset's index has the same blocks: blocks_total counts them
+    # once per subset the query ran
+    ix = eng._view().indexes[0]
+    n_sub = st["blocks_total"] // (ix.total_blocks if kind == "sharded"
+                                   else ix.n_blocks)
+    assert n_sub >= 1
+    assert st["accumulate_share"] == pytest.approx(
+        st["accumulate_rows"] / (eng.n * n_sub))
 
 
 # ----------------------------------------------------------------------

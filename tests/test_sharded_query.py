@@ -409,3 +409,58 @@ def test_shard_map_mesh_mode_matches_vmap_and_oracle():
     r = json.loads(line[len("RESULT:"):])
     assert r["used_mesh"], "8 devices available but the mesh was not used"
     assert r["mesh_eq_oracle"] and r["mesh_eq_vmap"], r
+
+
+def test_shard_map_mesh_dense_accumulate_matches_oracle():
+    """The dense score form on a real 4-device mesh: each shard scatters
+    its gathered rows by shard-local id (global id minus shard *
+    Nloc_max), and a subset that overflowed on ANY shard adds nothing on
+    every shard — a tiny capacity forces that retry — so the ranking is
+    bitwise the host oracle's."""
+    import json
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prog = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import json
+        import numpy as np
+        import jax
+        assert len(jax.devices()) == 4
+        from repro.core.engine import SearchEngine
+        from repro.data.synthetic import (PatchDatasetConfig,
+                                          generate_patches,
+                                          handcrafted_features)
+        data = generate_patches(PatchDatasetConfig(n_patches=1001, seed=3))
+        feats = handcrafted_features(data["images"])
+        labels = data["labels"]
+        pos = np.nonzero(labels == 2)[0][:10]
+        neg = np.nonzero(labels != 2)[0][:40]
+        kw = dict(n_subsets=6, subset_dim=5, block=16, seed=0)
+        host = SearchEngine(feats, **kw).query(pos, neg, model="dbens",
+                                                n_models=6)
+        em = SearchEngine(feats, n_shards=4, score_mode="dense",
+                          capacity_frac=0.01, **kw)
+        rm = em.query(pos, neg, model="dbens", n_models=6,
+                      max_results=em.n)
+        print("RESULT:" + json.dumps({
+            "used_mesh": em.shard_mesh is not None,
+            "retried": int(rm.stats["retried_subsets"]),
+            "eq_oracle": bool(np.array_equal(rm.ids, host.ids)
+                              and np.array_equal(rm.scores, host.scores)),
+        }))
+    """)
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                         text=True, env=env, timeout=900)
+    assert out.returncode == 0, f"stderr:\n{out.stderr[-4000:]}"
+    line = next(l for l in out.stdout.splitlines()
+                if l.startswith("RESULT:"))
+    r = json.loads(line[len("RESULT:"):])
+    assert r["used_mesh"], "4 devices available but the mesh was not used"
+    assert r["retried"] > 0, r
+    assert r["eq_oracle"], r

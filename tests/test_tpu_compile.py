@@ -111,3 +111,24 @@ def test_fused_query_compiles_for_v5e(one_chip):
         s(b, SUBSET_DIM), s(b, SUBSET_DIM), s(b, q))
     # both kernels of the program: zone_prune and box_scan_seg
     assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("n_rows,capacity", [(1_000_000, 256),
+                                             (590_326, 128)])
+def test_accumulate_scores_compiles_for_v5e(one_chip, n_rows, capacity):
+    """The dense score accumulate at the benchmark catalogs' sizes: a
+    survivor-sized scatter-add by row id into the [N, 1] buffer, and no
+    gather over the N rows in the compiled program."""
+    nb = -(-n_rows // BLOCK)
+    text = _compiled_text(
+        ops.accumulate_scores,
+        _spec(one_chip, n_rows, 1, dtype=jnp.int32),
+        _spec(one_chip, capacity, BLOCK, 1, dtype=jnp.int32),
+        _spec(one_chip, capacity, dtype=jnp.int32),
+        _spec(one_chip, dtype=jnp.int32),
+        _spec(one_chip, nb, BLOCK, dtype=jnp.int32))
+    lines = text.splitlines()
+    assert any(" scatter(" in l for l in lines)
+    # the only gather is the grid's C rows: none yields a catalog's rows
+    gathers = [l.split(" gather(")[0] for l in lines if " gather(" in l]
+    assert gathers and not any(str(n_rows) in g for g in gathers), gathers

@@ -216,6 +216,7 @@ def test_every_http_request_is_one_span_tree():
     assert last["rounds"] == len(rounds)
     assert last["n_host_syncs"] >= 1 and "blocks_touched" in last
     assert "retried_subsets" in last
+    assert "accumulate_rows" in last and "accumulate_share" in last
     # a request refused at parse time still leaves its (short) tree
     refused = [t for t in srv.obs.traces.recent(50)
                if t["status"] == "bad_request"]
